@@ -32,7 +32,7 @@ def _device_count_for(argv) -> int:
             exp = a.split("=", 1)[1]
     if exp in _POD_EXPS:
         return 512
-    if exp in ("sharded_serve", "chaos_restart"):
+    if exp == "sharded_serve":
         return 8
     return 1
 
@@ -1230,19 +1230,16 @@ _RESTART_SCENARIOS = {
 }
 
 
-def _restart_setup(scenario: str, smoke: bool, mesh_shape=None,
-                   fixture=None):
+def _restart_setup(scenario: str, smoke: bool, mesh_shape=None):
     """Deterministic engine ingredients for one chaos_restart scenario.
 
-    Shared between the parent experiment and the SIGKILL child process
-    (``benchmarks/restart_child.py``): both sides must build the exact
-    same model, experts, registry and request stream so the journal +
-    snapshot written by the killed child replays cleanly in the parent.
-    ``mesh_shape`` overrides the scenario's default mesh — the parent
-    uses this to resume onto a DIFFERENT shape than the one that
-    crashed.  ``fixture`` reuses a prebuilt ``_serve_fixture(3)`` (the
-    parent amortises the model compile across scenarios and trials).
-    Returns ``(api, rt, base, reg, mk_reqs, engine_kw)``.
+    Shared by the baseline, kill and resume child processes
+    (``benchmarks/restart_child.py``): all must build the exact same
+    model, experts, registry and request stream so the journal +
+    snapshot written by the killed child replays cleanly on resume.
+    ``mesh_shape`` overrides the scenario's default mesh — the resume
+    child uses this to resume onto a DIFFERENT shape than the one that
+    crashed.  Returns ``(api, rt, base, reg, mk_reqs, engine_kw)``.
     """
     import jax.numpy as jnp
 
@@ -1260,8 +1257,7 @@ def _restart_setup(scenario: str, smoke: bool, mesh_shape=None,
     # run must cross the snapshot-REPLAY tier, not just journal +
     # re-prefill (4 chunks per wave at decode_chunk=2, kill at 3)
     max_new = 8 if smoke else 10
-    api, rt, cfg, base, experts = \
-        fixture if fixture is not None else _serve_fixture(n_experts)
+    api, rt, cfg, base, experts = _serve_fixture(n_experts)
     rng = np.random.default_rng(0)
     prompts = [jnp.asarray(rng.integers(1, cfg.vocab, 8), jnp.int32)
                for _ in range(n_reqs)]
@@ -1289,13 +1285,14 @@ def exp_chaos_restart(smoke: bool = False):
     (2,4) mesh) a child process serves the seeded stream with per-chunk
     snapshots and ``SIGKILL``s itself from a chunk hook at a seeded
     chunk index — no atexit, no flush-on-exit: whatever survives is what
-    the journal/snapshot machinery made durable.  The parent then
-    resumes from the child's snapshot directory in-process and gates:
+    the journal/snapshot machinery made durable.  Another child then
+    resumes from that snapshot directory, and the parent (which never
+    touches JAX, so each child can hold the device) gates:
 
     * **kill** — the child really died by signal (``-SIGKILL``), having
       journaled at least one chunk first;
     * **parity** — every resumed request finishes with tokens
-      bit-identical to an uninterrupted in-process run (the mesh
+      bit-identical to an uninterrupted run in a third child (the mesh
       scenario resumes onto a DIFFERENT shape, (4,2), than it crashed
       on);
     * **determinism** — a second kill–resume trial reproduces the same
@@ -1308,63 +1305,63 @@ def exp_chaos_restart(smoke: bool = False):
     import sys as _sys
     import tempfile
 
-    from repro import api as capi
     from repro.serve import DONE
 
-    if len(jax.devices()) < 8:
-        raise SystemExit("chaos_restart needs 8 devices — run via "
-                         "`--exp chaos_restart` so the XLA flag is set "
-                         "before jax imports")
-
+    # One process per device: this parent never touches JAX; the
+    # baseline, the killed run and each resume are children in turn.
     child = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                          "restart_child.py")
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)    # each child picks its own device count
+
+    def run_child(*args, expect=0):
+        proc = subprocess.run(
+            [_sys.executable, child, *map(str, args)], env=env,
+            capture_output=True, text=True, timeout=1800)
+        assert proc.returncode == expect, (
+            f"{args[:2]}: rc={proc.returncode} (want {expect})\n"
+            f"{proc.stdout}\n{proc.stderr}")
+
+    def load(path):
+        with open(path) as f:
+            rec = json.load(f)
+        rec["tokens"] = {int(k): (v[0], v[1])
+                         for k, v in rec["tokens"].items()}
+        return rec
+
     kill_at = 3
     n_trials = 2
     resume_mesh = {"paged_greedy_mesh": (4, 2)}
-    fixture = _serve_fixture(n_experts=3)
     rows, parity_all, determ_all = [], True, True
 
     for scenario in _RESTART_SCENARIOS:
-        # uninterrupted baseline (scenario's own mesh shape)
-        api, rt, base, reg, mk_reqs, engine_kw = _restart_setup(
-            scenario, smoke, fixture=fixture)
-        reqs = mk_reqs()
-        capi.serve(api, rt, base, reg, **engine_kw).run(reqs)
-        assert all(r.status == DONE for r in reqs)
-        want = {r.uid: (r.status, list(r.out_tokens)) for r in reqs}
-        reg.close()
+        with tempfile.TemporaryDirectory() as tmp:
+            # uninterrupted baseline (scenario's own mesh shape)
+            run_child("baseline", scenario, int(smoke),
+                      os.path.join(tmp, "want.json"))
+            want = load(os.path.join(tmp, "want.json"))["tokens"]
+            assert all(st == DONE for st, _ in want.values())
 
-        trials, outcomes = [], []
-        for trial in range(n_trials):
-            with tempfile.TemporaryDirectory() as snap_dir:
-                env = dict(os.environ)
-                env.pop("XLA_FLAGS", None)    # child picks its own count
-                proc = subprocess.run(
-                    [_sys.executable, child, snap_dir, scenario,
-                     str(kill_at), str(int(smoke))],
-                    env=env, capture_output=True, text=True, timeout=1800)
-                assert proc.returncode == -_signal.SIGKILL, (
-                    f"{scenario}: child survived or failed "
-                    f"(rc={proc.returncode})\n{proc.stdout}\n{proc.stderr}")
-
-                api, rt, base, reg, mk_reqs, engine_kw = _restart_setup(
-                    scenario, smoke,
-                    mesh_shape=resume_mesh.get(scenario), fixture=fixture)
-                eng = capi.serve(api, rt, base, reg, snapshot_dir=snap_dir,
-                                 snapshot_every_chunks=1, **engine_kw)
-                out = eng.resume()
-                reg.close()
-            got = {r.uid: (r.status, list(r.out_tokens)) for r in out}
-            plan = eng.recovery_stats["plan"]
-            ok = got == want
-            parity_all = parity_all and ok
-            outcomes.append((sorted(got.items()), plan.as_dict()))
-            trials.append({
-                "parity": ok,
-                "resume_seconds": eng.recovery_stats["resume_seconds"],
-                "first_resumed_token_s":
-                    eng.recovery_stats.get("first_resumed_token_s"),
-                **plan.as_dict()})
+            trials, outcomes = [], []
+            for trial in range(n_trials):
+                snap_dir = os.path.join(tmp, f"snap{trial}")
+                out_path = os.path.join(tmp, f"resume{trial}.json")
+                run_child("kill", scenario, int(smoke), snap_dir, kill_at,
+                          expect=-_signal.SIGKILL)
+                mesh = resume_mesh.get(scenario)
+                run_child("resume", scenario, int(smoke), snap_dir,
+                          out_path,
+                          *(["x".join(map(str, mesh))] if mesh else []))
+                res = load(out_path)
+                got, plan = res["tokens"], res["plan"]
+                ok = got == want
+                parity_all = parity_all and ok
+                outcomes.append((sorted(got.items()), plan))
+                trials.append({
+                    "parity": ok,
+                    "resume_seconds": res["resume_seconds"],
+                    "first_resumed_token_s": res["first_resumed_token_s"],
+                    **plan})
         deterministic = outcomes[0] == outcomes[-1]
         determ_all = determ_all and deterministic
         row = {"scenario": scenario, "kill_at": kill_at,
@@ -1414,6 +1411,8 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="reduced sizes for CI (skips the speedup gate)")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     def call(f):
         if args.smoke and "smoke" in inspect.signature(f).parameters:

@@ -20,7 +20,11 @@ import glob
 import json
 import os
 
-HW = dict(peak_flops=197e12, hbm_bw=819e9, link_bw=50e9)
+from repro.launch.mesh import device_peaks
+
+_V5E = device_peaks("TPU v5 lite")
+HW = dict(peak_flops=_V5E["peak_flops_bf16"], hbm_bw=_V5E["hbm_bw"],
+          link_bw=_V5E["ici_link_bw"])
 RESULTS = os.path.join(os.path.dirname(__file__), "results", "dryrun")
 
 

@@ -424,6 +424,8 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", type=str, default=None)
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for b in BENCHES:
         if args.only and args.only not in b.__name__:

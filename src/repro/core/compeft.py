@@ -17,6 +17,7 @@ or global (one threshold across the whole pytree).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import jax
@@ -173,40 +174,104 @@ STREAM_COLS = 8192  # segment-buffer row width; multiple of the pack kernel's
                     # 32-bit lane and of its default 512-column block
 
 
-def _build_segment_buffer(leaves, cols: int):
-    """Concatenate flattened leaves into a padded [R, cols] buffer.
+def _segment_cols(leaves) -> int:
+    """Row width of the segment buffer.
+
+    A one-leaf call whose last dim is a whole number of 32-bit words keeps
+    that dim as the row width (the rule ``ops._merge_view`` applies to
+    merges): the buffer is then the leaf with its leading dims merged, and
+    on a TPU that view keeps the tiled layout, where ``[R, STREAM_COLS]``
+    would cost a full-leaf relayout copy (3.25 GB for a 3B model's f32
+    FFN stack).  The planes are packed over the flat C-order leaf, so the
+    bits do not depend on the row width.  Other calls use ``STREAM_COLS``.
+    """
+    from repro.core.packing import LANE
+    if len(leaves) == 1:
+        shape = leaves[0].shape
+        if len(shape) >= 2 and shape[-1] % LANE == 0:
+            return int(shape[-1])
+    return STREAM_COLS
+
+
+def _segment_layout(shapes, cols: int):
+    """Host-side layout of the segment buffer over leaves of ``shapes``.
 
     Each leaf is padded to a whole number of rows so every row belongs to
     exactly one leaf (segment); that is what lets one kernel launch carry
-    per-leaf thresholds as a per-row vector.  Returns the buffer plus the
-    row->segment map, per-row valid counts, per-segment element counts and
-    each leaf's (row_start, row_end).
+    per-leaf thresholds as a per-row vector.  Returns the row->segment
+    map, per-row valid counts, per-segment element counts and each leaf's
+    (row_start, row_end).
     """
-    chunks, row_seg, row_valid, spans = [], [], [], []
+    row_seg, row_valid, counts, spans = [], [], [], []
     r = 0
-    for i, leaf in enumerate(leaves):
-        n = int(np.prod(leaf.shape))
+    for i, shape in enumerate(shapes):
+        n = int(np.prod(shape))
         rows = -(-n // cols)
-        flat = leaf.reshape(-1).astype(jnp.float32)
-        pad = rows * cols - n
-        if pad:
-            flat = jnp.concatenate([flat, jnp.zeros((pad,), jnp.float32)])
-        chunks.append(flat.reshape(rows, cols))
         row_seg.append(np.full(rows, i, np.int32))
         valid = np.full(rows, cols, np.int32)
         valid[-1] = n - (rows - 1) * cols
         row_valid.append(valid)
+        counts.append(n)
         spans.append((r, r + rows))
         r += rows
-    buf = jnp.concatenate(chunks) if len(chunks) > 1 else chunks[0]
-    return (buf, jnp.asarray(np.concatenate(row_seg)),
-            jnp.asarray(np.concatenate(row_valid)),
-            jnp.asarray([int(np.prod(l.shape)) for l in leaves], jnp.int32),
-            spans)
+    return (np.concatenate(row_seg), np.concatenate(row_valid),
+            np.asarray(counts, np.int32), tuple(spans))
+
+
+def _segment_rows(leaves, cols: int):
+    """The ``[R, cols]`` f32 segment buffer of ``leaves`` (traceable).
+    Inside one compiled program a leaf that needs no padding is a view:
+    its leading dims merge, and with ``cols`` its own last dim the TPU's
+    tiled layout is unchanged."""
+    chunks = []
+    for leaf in leaves:
+        n = int(np.prod(leaf.shape))
+        rows = -(-n // cols)
+        x = leaf.astype(jnp.float32)
+        if rows * cols != n:
+            x = jnp.pad(x.reshape(-1), (0, rows * cols - n))
+        chunks.append(x.reshape(rows, cols))
+    return jnp.concatenate(chunks) if len(chunks) > 1 else chunks[0]
+
+
+def _build_segment_buffer(leaves, cols: int):
+    """Segment buffer plus its layout (see :func:`_segment_layout`)."""
+    row_seg, row_valid, seg_count, spans = _segment_layout(
+        [l.shape for l in leaves], cols)
+    return (_segment_rows(leaves, cols), jnp.asarray(row_seg),
+            jnp.asarray(row_valid), jnp.asarray(seg_count), spans)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("cols", "spans", "n_seg", "interpret"))
+def _stream_compress(leaves, seg_ids, row_valid, seg_count, keep, *,
+                     cols: int, spans, n_seg: int, interpret: bool):
+    """Segment buffer -> histogram threshold + moments -> packed planes, as
+    one program, so the buffer is never an eager copy of the leaves.
+    Returns the per-segment stats and each leaf's flat (pos, neg) words."""
+    from repro.core.packing import LANE
+    from repro.kernels.histogram_quantile import NBINS, _quantile_moments
+    from repro.kernels.pack import (pack_ternary_planes_segmented,
+                                    pack_ternary_planes_segmented_ref)
+
+    buf = _segment_rows(leaves, cols)
+    stats = _quantile_moments(buf, seg_ids, row_valid, seg_count, keep,
+                              n_seg=n_seg, nbins=NBINS)
+    thr_rows = stats["threshold"][seg_ids]
+    if interpret:   # vectorised jnp mirror: same math, no interpreter tax
+        pos, neg = pack_ternary_planes_segmented_ref(buf, thr_rows)
+    else:
+        pos, neg = pack_ternary_planes_segmented(buf, thr_rows,
+                                                 interpret=False)
+    planes = []
+    for leaf, (r0, r1) in zip(leaves, spans):
+        nw = -(-int(np.prod(leaf.shape)) // LANE)
+        planes.append((pos[r0:r1].reshape(-1)[:nw],
+                       neg[r0:r1].reshape(-1)[:nw]))
+    return stats, planes
 
 
 def compress_packed(tau: PyTree, cfg: CompressionConfig | None = None, *,
-                    cols: int = STREAM_COLS,
                     return_stats: bool = False) -> PyTree:
     """Algorithm 1 straight to packed bitplanes, in one streaming pipeline.
 
@@ -217,28 +282,35 @@ def compress_packed(tau: PyTree, cfg: CompressionConfig | None = None, *,
     launch with per-row thresholds.  Returns a pytree of
     :class:`~repro.core.packing.PackedTernary` (2 bits/param), the format
     the serving cache keeps resident and the merge kernels consume.
+
+    :func:`_segment_cols` picks the segment buffer's row width.  Under
+    ``per_tensor=True`` compressing a tree one leaf at a time keeps the
+    same thresholds and bits (scales may differ in the last f32 ulp, from
+    the summation order), and a call then holds only that leaf's f32
+    buffer.
     """
-    from repro.core.packing import LANE, PackedTernary
-    from repro.kernels.histogram_quantile import segmented_quantile_moments
+    from repro.core.packing import PackedTernary
+    from repro.kernels.histogram_quantile import keep_counts
     from repro.kernels.ops import INTERPRET
-    from repro.kernels.pack import (pack_ternary_planes_segmented,
-                                    pack_ternary_planes_segmented_ref)
 
     cfg = cfg or CompressionConfig()
     leaves, treedef = jax.tree_util.tree_flatten(tau)
     if not leaves:
         return jax.tree_util.tree_unflatten(treedef, [])
-    buf, row_seg, row_valid, seg_count, spans = _build_segment_buffer(
-        leaves, cols)
+    cols = _segment_cols(leaves)
+    row_seg, row_valid, seg_count, spans = _segment_layout(
+        [l.shape for l in leaves], cols)
 
     if cfg.per_tensor:
         n_seg, seg_ids = len(leaves), row_seg
     else:       # one global threshold/scale over the concatenated vector
-        n_seg, seg_ids = 1, jnp.zeros_like(row_seg)
-        seg_count = jnp.sum(seg_count, keepdims=True)
-    stats = segmented_quantile_moments(
-        buf, seg_ids, row_valid, seg_count, cfg.density, n_seg=n_seg,
-        interpret=INTERPRET)
+        n_seg, seg_ids = 1, np.zeros_like(row_seg)
+        seg_count = np.sum(seg_count, keepdims=True).astype(np.int32)
+    stats, planes = _stream_compress(
+        tuple(leaves), jnp.asarray(seg_ids), jnp.asarray(row_valid),
+        jnp.asarray(seg_count), jnp.asarray(keep_counts(seg_count,
+                                                        cfg.density)),
+        cols=cols, spans=spans, n_seg=n_seg, interpret=INTERPRET)
 
     if cfg.scale_mode == "std":
         sigma = stats["std"]
@@ -248,26 +320,10 @@ def compress_packed(tau: PyTree, cfg: CompressionConfig | None = None, *,
         sigma = jnp.ones((n_seg,), jnp.float32)
     scales = jnp.asarray(cfg.alpha, jnp.float32) * sigma
 
-    thr_rows = stats["threshold"][seg_ids]
-    if INTERPRET:   # vectorised jnp mirror: same math, no interpreter tax
-        pos, neg = pack_ternary_planes_segmented_ref(buf, thr_rows)
-    else:
-        pos, neg = pack_ternary_planes_segmented(buf, thr_rows,
-                                                 interpret=False)
-
-    out = []
-    for i, leaf in enumerate(leaves):
-        n = int(np.prod(leaf.shape))
-        nw = -(-n // LANE)
-        r0, r1 = spans[i]
-        s = 0 if not cfg.per_tensor else i
-        out.append(PackedTernary(
-            pos=pos[r0:r1].reshape(-1)[:nw],
-            neg=neg[r0:r1].reshape(-1)[:nw],
-            scale=scales[s],
-            shape=tuple(leaf.shape),
-            orig_dtype=leaf.dtype,
-        ))
+    out = [PackedTernary(pos=pos, neg=neg,
+                         scale=scales[i if cfg.per_tensor else 0],
+                         shape=tuple(leaf.shape), orig_dtype=leaf.dtype)
+           for i, (leaf, (pos, neg)) in enumerate(zip(leaves, planes))]
     packed = jax.tree_util.tree_unflatten(treedef, out)
     if return_stats:
         return packed, stats

@@ -3,7 +3,7 @@
 The paper: with two binary masks per vector, dot products and distances
 reduce to AND/XOR + POPCNT.  On TPU, ``lax.population_count`` runs on the
 VPU over uint32 lanes (32 params/lane).  These are the pure-jnp versions;
-:mod:`repro.kernels.popcount_dot` is the tiled Pallas variant.
+:func:`repro.kernels.ops.expert_dot` is the serving-side entry point.
 """
 
 from __future__ import annotations

@@ -165,7 +165,14 @@ def make_shard_fn(mesh: Mesh, cfg: ModelConfig,
     manual shard_map regions (e.g. the 'pod'-manual compressed-gradient
     scope, where 'pod' may not appear in GSPMD constraints).
     """
-    baxes = tuple(a for a in batch_axes(mesh) if a not in drop_axes)
+    # Inside a manual region the batch dim is left to the partitioner
+    # (UNCONSTRAINED; None would pin it replicated).  A ('data',)
+    # constraint there, with 'pod' Manual and 'data' Auto, aborts SPMD
+    # partitioning on the installed XLA (a replica-group check).  The
+    # partitioner then keeps the pod batch replicated over 'data': each
+    # data shard computes the whole pod microbatch (see PERF.md, open
+    # questions).
+    baxes = P.UNCONSTRAINED if drop_axes else batch_axes(mesh)
     n_model = mesh.shape["model"]
 
     def shard(x, axes):

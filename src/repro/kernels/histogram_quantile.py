@@ -6,9 +6,7 @@ then re-read the data for ``std``.  This module replaces it with a two-pass
 segmented histogram scheme over a single flat buffer holding *all* leaves of
 a pytree:
 
-  pass 1 (coarse)  — 2048-bin histogram of |tau| per segment over
-                     ``[0, max_s]``, accumulating ``sum``/``sum_sq`` in the
-                     same sweep so sigma comes for free;
+  pass 1 (coarse)  — 2048 bins of |tau| per segment over ``[0, max_s]``;
   pass 2 (refine)  — 2048 sub-bins inside the coarse bin that contains the
                      k-th largest magnitude.
 
@@ -17,13 +15,16 @@ k-th order statistic, so it is within ``max_s / 2048^2`` of the exact
 quantile and — crucially for Algorithm 1 — ``|x| >= thr`` keeps the same
 top-k set as the exact threshold for every distribution, including ties.
 
-Two implementations with identical semantics:
-
-* ``*_jnp``    — vectorised scatter-add path (used off-TPU; O(n) and fully
-                 batched, this is what the CPU perf numbers measure);
-* Pallas kernel — bin-chunked compare-accumulate grid kernel for TPU, with
-                 the moments fused into the coarse pass.  Validated against
-                 the jnp path in interpret mode by the test suite.
+How a pass finds its bin.  The scheme needs only the histogram's suffix
+counts: ``S(b)`` = how many in-range magnitudes fall in bin ``b`` or
+above, and the bin holding the k-th largest is the last ``b`` with
+``S(b) >= k``.  ``S`` is monotone, so each pass finds that bin by an
+11-step binary search, one fused compare-and-count reduction over the
+buffer per step.  No histogram array, no scatter over elements and no
+per-element bin-index array is ever built, so memory stays at the buffer
+itself (a 3.25 GB f32 leaf compresses on one 16 GB chip) and every step is
+a bandwidth-bound sweep.  The same jnp code runs on every platform.  The
+moments (sum, sum of squares, sum |x|, max) take one more sweep.
 
 Layout contract (shared with :func:`repro.core.compeft.compress_packed`):
 leaves are flattened C-order, each padded to a multiple of ``cols`` so a
@@ -38,276 +39,84 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
 NBINS = 2048
-_BIN_CHUNK = 256   # bins compared per inner step inside the Pallas kernel
 
 
-# ---------------------------------------------------------------------------
-# Vectorised jnp path (CPU / interpret default)
-# ---------------------------------------------------------------------------
+def _seg_sum(rows, row_seg, n_seg: int):
+    return jax.ops.segment_sum(rows, row_seg, num_segments=n_seg)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_seg", "nbins", "with_moments"))
-def _segment_hist_moments_jnp(buf, row_seg, row_valid, lo, width, *,
-                              n_seg: int, nbins: int,
-                              with_moments: bool = True):
-    """One histogram sweep: buf [R, C] -> (hist [S, nbins], sum, sumsq [S]).
+def _suffix_count(buf, valid, row_seg, lo, width, cand, *, n_seg: int,
+                  nbins: int):
+    """Per segment: in-range magnitudes whose bin is >= ``cand`` [S].
 
-    Elements are binned by ``(|x| - lo_s) / width_s`` and clipped into
-    [0, nbins-1]; padding (col index >= row_valid) is dropped from every
-    accumulator.  ``lo``/``width`` are per-segment f32 vectors.
-    """
+    Bins are ``(|x| - lo_s) * (nbins / width_s)`` floored and clipped to
+    [0, nbins-1]; in range means ``lo_s <= |x| <= lo_s + width_s``.  For
+    ``0 <= cand < nbins`` the clipped bin is >= cand exactly when the
+    unfloored position is, so no bin index is materialised."""
+    mag = jnp.abs(buf.astype(jnp.float32))
+    w = jnp.maximum(width, 1e-30)
+    lo_r = lo[row_seg][:, None]
+    pos = (mag - lo_r) * (nbins / w)[row_seg][:, None]
+    hit = (valid & (mag >= lo_r) & (mag <= lo_r + w[row_seg][:, None])
+           & (pos >= cand.astype(jnp.float32)[row_seg][:, None]))
+    return _seg_sum(jnp.sum(hit, axis=1, dtype=jnp.int32), row_seg, n_seg)
+
+
+def _last_bin_reaching(count, keep, *, n_seg: int, nbins: int):
+    """Largest bin b with ``count(b) >= keep`` per segment (0 when none):
+    binary search over the monotone suffix count."""
+    ans = jnp.zeros((n_seg,), jnp.int32)
+    for bit in reversed(range((nbins - 1).bit_length())):
+        cand = ans + (1 << bit)
+        ok = (cand < nbins) & (count(jnp.minimum(cand, nbins - 1)) >= keep)
+        ans = jnp.where(ok, cand, ans)
+    return ans
+
+
+@functools.partial(jax.jit, static_argnames=("n_seg", "nbins"))
+def _quantile_moments(buf, row_seg, row_valid, seg_count, keep, *,
+                      n_seg: int, nbins: int):
     R, C = buf.shape
     x = buf.astype(jnp.float32)
-    mag = jnp.abs(x)
-    valid = (jnp.arange(C, dtype=jnp.int32)[None, :]
-             < row_valid[:, None])                      # [R, C]
-    seg = jnp.broadcast_to(row_seg[:, None], (R, C))
-    w = jnp.maximum(width[seg], 1e-30)
-    b = jnp.clip(((mag - lo[seg]) / w * nbins).astype(jnp.int32), 0, nbins - 1)
-    in_range = valid & (mag >= lo[seg]) & (mag <= lo[seg] + w)
-    hist = jnp.zeros((n_seg, nbins), jnp.int32).at[
-        seg.reshape(-1), b.reshape(-1)].add(in_range.reshape(-1)
-                                            .astype(jnp.int32))
-    if not with_moments:
-        z = jnp.zeros((n_seg,), jnp.float32)
-        return hist, z, z, z, z
+    valid = (jax.lax.broadcasted_iota(jnp.int32, (R, C), 1)
+             < row_valid[:, None])
     xm = jnp.where(valid, x, 0.0)
-    magm = jnp.where(valid, mag, 0.0)
-    ssum = jnp.zeros((n_seg,), jnp.float32).at[row_seg].add(
-        jnp.sum(xm, axis=1))
-    ssq = jnp.zeros((n_seg,), jnp.float32).at[row_seg].add(
-        jnp.sum(xm * xm, axis=1))
-    smax = jnp.zeros((n_seg,), jnp.float32).at[row_seg].max(
-        jnp.max(magm, axis=1))
-    sabs = jnp.zeros((n_seg,), jnp.float32).at[row_seg].add(
-        jnp.sum(magm, axis=1))
-    return hist, ssum, ssq, smax, sabs
+    magm = jnp.abs(xm)
+    smax = jax.ops.segment_max(jnp.max(magm, axis=1), row_seg,
+                               num_segments=n_seg)
+    smax = jnp.maximum(smax, 0.0)                  # empty segments -> 0
+    ssum = _seg_sum(jnp.sum(xm, axis=1), row_seg, n_seg)
+    ssq = _seg_sum(jnp.sum(xm * xm, axis=1), row_seg, n_seg)
+    sabs = _seg_sum(jnp.sum(magm, axis=1), row_seg, n_seg)
 
+    count = functools.partial(_suffix_count, buf, valid, row_seg,
+                              n_seg=n_seg, nbins=nbins)
+    search = functools.partial(_last_bin_reaching, n_seg=n_seg, nbins=nbins)
 
-# ---------------------------------------------------------------------------
-# Pallas kernel path (TPU): bin-chunked compare-accumulate
-# ---------------------------------------------------------------------------
+    zeros = jnp.zeros((n_seg,), jnp.float32)
+    cb = search(lambda c: count(zeros, smax, c), keep)          # coarse bin
+    cw = jnp.maximum(smax, 1e-30) / nbins
+    lo1 = cb.astype(jnp.float32) * cw
+    # rank of the target inside the selected coarse bin
+    above = jnp.where(cb + 1 < nbins,
+                      count(zeros, smax, jnp.minimum(cb + 1, nbins - 1)), 0)
+    keep_in_bin = jnp.maximum(keep - above, 1)
+    rb = search(lambda c: count(lo1, cw, c), keep_in_bin)       # refined bin
+    thr = jnp.where(smax > 0.0, lo1 + rb.astype(jnp.float32) * (cw / nbins),
+                    0.0)
 
-
-def _hist_kernel(buf_ref, seg_ref, valid_ref, lo_ref, width_ref,
-                 hist_ref, mom_ref, *, n_seg: int, nbins: int,
-                 with_moments: bool):
-    """Grid (n_row_chunks,): accumulate [S, nbins] histogram + [S, 3]
-    moments (sum, sumsq, max) across sequential row-chunk steps."""
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _init():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-        if with_moments:
-            mom_ref[...] = jnp.zeros_like(mom_ref)
-
-    x = buf_ref[...].astype(jnp.float32)                 # [BR, C]
-    br, c = x.shape
-    mag = jnp.abs(x)
-    seg = seg_ref[...].reshape(br)                       # [BR] int32
-    nvalid = valid_ref[...].reshape(br)
-    valid = (jax.lax.broadcasted_iota(jnp.int32, (br, c), 1)
-             < nvalid[:, None])
-    lo = lo_ref[...].reshape(-1)[seg][:, None]           # [BR, 1]
-    w = jnp.maximum(width_ref[...].reshape(-1)[seg], 1e-30)[:, None]
-    b = jnp.clip(((mag - lo) / w * nbins).astype(jnp.int32), 0, nbins - 1)
-    in_range = valid & (mag >= lo) & (mag <= lo + w)
-    b = jnp.where(in_range, b, -1)                       # park padding
-
-    # per-row one-hot over a bin chunk, then segment scatter via matmul:
-    #   seg_onehot [S, BR] @ rowhist [BR, chunk] -> [S, chunk]
-    seg_onehot = (jax.lax.broadcasted_iota(jnp.int32, (n_seg, br), 0)
-                  == seg[None, :]).astype(jnp.float32)
-    for b0 in range(0, nbins, _BIN_CHUNK):
-        ids = b0 + jax.lax.broadcasted_iota(jnp.int32, (1, 1, _BIN_CHUNK), 2)
-        rowhist = jnp.sum((b[:, :, None] == ids), axis=1,
-                          dtype=jnp.float32)             # [BR, chunk]
-        upd = jnp.dot(seg_onehot, rowhist,
-                      preferred_element_type=jnp.float32)
-        hist_ref[:, b0:b0 + _BIN_CHUNK] += upd.astype(jnp.int32)
-
-    if with_moments:
-        xm = jnp.where(valid, x, 0.0)
-        magm = jnp.where(valid, mag, 0.0)
-        s1 = seg_onehot @ jnp.sum(xm, axis=1)
-        s2 = seg_onehot @ jnp.sum(xm * xm, axis=1)
-        s3 = seg_onehot @ jnp.sum(magm, axis=1)
-        rmax = jnp.max(magm, axis=1)
-        cand = jnp.max(jnp.where(seg_onehot > 0, rmax[None, :], 0.0), axis=1)
-        m = mom_ref[...]
-        mom_ref[...] = jnp.stack(
-            [m[:, 0] + s1, m[:, 1] + s2, jnp.maximum(m[:, 2], cand),
-             m[:, 3] + s3], axis=1)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_seg", "nbins", "br", "with_moments",
-                                    "interpret"))
-def segment_hist_moments_pallas(buf, row_seg, row_valid, lo, width, *,
-                                n_seg: int, nbins: int = NBINS, br: int = 8,
-                                with_moments: bool = True,
-                                interpret: bool = True):
-    """Pallas version of :func:`_segment_hist_moments_jnp` (hist, sum, sumsq,
-    max, sum|x|).  ``buf`` [R, C]; rows are padded here to a multiple of
-    ``br`` with ``row_valid == 0`` rows (aliased to segment 0), which
-    contribute to no accumulator."""
-    from repro.kernels.tpu_params import tpu_compiler_params
-
-    R, C = buf.shape
-    br = min(br, R)
-    pad = (-R) % br
-    if pad:
-        buf = jnp.pad(buf, ((0, pad), (0, 0)))
-        row_seg = jnp.pad(row_seg.reshape(-1), (0, pad))
-        row_valid = jnp.pad(row_valid.reshape(-1), (0, pad))
-        R += pad
-    grid = (R // br,)
-    hist, mom = pl.pallas_call(
-        functools.partial(_hist_kernel, n_seg=n_seg, nbins=nbins,
-                          with_moments=with_moments),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((br, C), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_seg), lambda i: (0, 0)),
-            pl.BlockSpec((1, n_seg), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((n_seg, nbins), lambda i: (0, 0)),
-            pl.BlockSpec((n_seg, 4), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_seg, nbins), jnp.int32),
-            jax.ShapeDtypeStruct((n_seg, 4), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(("arbitrary",),
-                                            interpret=interpret),
-        interpret=interpret,
-    )(buf, row_seg.reshape(-1, 1), row_valid.reshape(-1, 1),
-      lo.reshape(1, -1), width.reshape(1, -1))
-    return hist, mom[:, 0], mom[:, 1], mom[:, 2], mom[:, 3]
-
-
-# ---------------------------------------------------------------------------
-# Host numpy fast path (off-TPU default): np.bincount histograms
-# ---------------------------------------------------------------------------
-
-
-def _quantile_moments_np(buf, row_seg, row_valid, seg_count, density, *,
-                         n_seg: int, nbins: int):
-    """Whole two-pass scheme on the host with C-speed ``np.bincount``.
-
-    XLA lowers the segment scatter-add to a serial loop that is ~10x slower
-    than numpy's bincount on CPU, and interpret-mode Pallas is slower
-    still, so off-TPU the sweeps run here.  Semantics are identical to the
-    jnp/Pallas paths (same binning, same refine, same moments); padding is
-    handled by subtracting the known pad count from bin 0 instead of
-    masking, so |x| is computed once and reused by both passes.
-    """
-    buf = np.asarray(buf)
-    row_seg = np.asarray(row_seg)
-    row_valid = np.asarray(row_valid)
-    seg_count = np.asarray(seg_count)
-    R, C = buf.shape
-    mag = np.abs(buf, dtype=np.float32)                   # reused by pass 2
-
-    n = seg_count.astype(np.float64)
-    keep = np.maximum(np.round(n * density), 1.0).astype(np.int64)
-    pad = np.bincount(row_seg, weights=(C - row_valid),
-                      minlength=n_seg).astype(np.int64)
-
-    rmax = mag.max(axis=1)
-    smax = np.zeros(n_seg, np.float32)
-    np.maximum.at(smax, row_seg, rmax)
-    ssum = np.bincount(row_seg, weights=buf.sum(axis=1, dtype=np.float64),
-                       minlength=n_seg)
-    ssq = np.bincount(row_seg,
-                      weights=np.einsum("rc,rc->r", buf, buf,
-                                        dtype=np.float64),
-                      minlength=n_seg)
-    sabs = np.bincount(row_seg, weights=mag.sum(axis=1, dtype=np.float64),
-                       minlength=n_seg)
-
-    def hist_pass(lo, width):
-        w = np.maximum(width, 1e-30)
-        lo_r = lo[row_seg][:, None]
-        scale_r = (nbins / w)[row_seg][:, None]
-        b = ((mag - lo_r) * scale_r).astype(np.int64)
-        np.clip(b, 0, nbins - 1, out=b)
-        idx = row_seg[:, None] * np.int64(nbins) + b
-        # out-of-range (refine pass) and padding go to a trash bin
-        oob = (mag < lo_r) | (mag > lo_r + w[row_seg][:, None])
-        if oob.any():
-            idx = np.where(oob, n_seg * nbins, idx)
-        h = np.bincount(idx.ravel(), minlength=n_seg * nbins + 1)
-        h = h[:n_seg * nbins].reshape(n_seg, nbins)
-        h[:, 0] -= np.where(lo <= 0.0, pad, 0)           # padded zeros
-        return h
-
-    coarse = hist_pass(np.zeros(n_seg, np.float32), smax)
-    suffix = np.cumsum(coarse[:, ::-1], axis=1)[:, ::-1]
-    ge = suffix >= keep[:, None]
-    cb = np.maximum((ge * np.arange(nbins)[None, :]).max(axis=1), 0)
-    cw = np.maximum(smax, 1e-30) / nbins
-    lo1 = cb.astype(np.float32) * cw
-    above = np.where(cb + 1 < nbins,
-                     np.take_along_axis(
-                         np.pad(suffix, ((0, 0), (0, 1))),
-                         (cb + 1)[:, None], axis=1)[:, 0], 0)
-    keep_in_bin = np.maximum(keep - above, 1)
-
-    refined = hist_pass(lo1, cw)
-    suffix2 = np.cumsum(refined[:, ::-1], axis=1)[:, ::-1]
-    ge2 = suffix2 >= keep_in_bin[:, None]
-    rb = np.maximum((ge2 * np.arange(nbins)[None, :]).max(axis=1), 0)
-    thr = np.where(smax > 0.0, lo1 + rb.astype(np.float32) * (cw / nbins),
-                   0.0)
-
-    nmax = np.maximum(n, 1.0)
-    mean = ssum / nmax
-    var = np.maximum(ssq / nmax - mean * mean, 0.0)
-    as32 = lambda v: jnp.asarray(v, jnp.float32)  # noqa: E731
-    return {"threshold": as32(thr), "mean": as32(mean),
-            "std": as32(np.sqrt(var)), "mean_abs": as32(sabs / nmax),
-            "max": as32(smax), "sum": as32(ssum), "sumsq": as32(ssq),
-            "keep": jnp.asarray(keep, jnp.int32)}
-
-
-@functools.partial(jax.jit, static_argnames=("n_seg",))
-def _segment_absmax(buf, row_seg, row_valid, *, n_seg: int):
-    R, C = buf.shape
-    valid = (jnp.arange(C, dtype=jnp.int32)[None, :] < row_valid[:, None])
-    mag = jnp.where(valid, jnp.abs(buf.astype(jnp.float32)), 0.0)
-    return jnp.zeros((n_seg,), jnp.float32).at[row_seg].max(
-        jnp.max(mag, axis=1))
-
-
-# ---------------------------------------------------------------------------
-# Threshold selection from histograms (host-side jnp, O(S * nbins))
-# ---------------------------------------------------------------------------
-
-
-def _select_bin(hist, keep):
-    """Smallest bin index b with suffix_count(b) >= keep (the bin holding
-    the keep-th largest in-range magnitude).  hist [S, B], keep [S]."""
-    suffix = jnp.cumsum(hist[:, ::-1], axis=1)[:, ::-1]        # [S, B]
-    ge = suffix >= keep[:, None]
-    # last True index (ge is monotone non-increasing along bins)
-    idx = jnp.max(jnp.where(ge, jnp.arange(hist.shape[1])[None, :], -1),
-                  axis=1)
-    return jnp.maximum(idx, 0)
+    n = jnp.maximum(seg_count.astype(jnp.float32), 1.0)
+    mean = ssum / n
+    var = jnp.maximum(ssq / n - mean * mean, 0.0)
+    return {"threshold": thr, "mean": mean, "std": jnp.sqrt(var),
+            "mean_abs": sabs / n, "max": smax, "sum": ssum, "sumsq": ssq,
+            "keep": keep}
 
 
 def segmented_quantile_moments(buf, row_seg, row_valid, seg_count, density,
-                               *, n_seg: int, nbins: int = NBINS,
-                               backend: str = "auto",
-                               interpret: bool = True):
+                               *, n_seg: int, nbins: int = NBINS):
     """Two-pass histogram threshold + moments over a segment buffer.
 
     Args:
@@ -316,55 +125,20 @@ def segmented_quantile_moments(buf, row_seg, row_valid, seg_count, density,
       row_valid: [R] int32 valid element count per row.
       seg_count: [S] int32 total element count per segment.
       density:   fraction of entries to keep (Algorithm 1 ``k``).
-      backend:   'pallas' (TPU kernel), 'jnp' (differentiable/jit
-                 reference), 'numpy' (host bincount fast path), or 'auto'
-                 — pallas on a real TPU, numpy otherwise.
 
     Returns dict with per-segment f32 vectors: ``threshold``, ``mean``,
-    ``std``, ``mean_abs``, ``max`` — everything Algorithm 1 needs, in two
-    data sweeps.
+    ``std``, ``mean_abs``, ``max`` — everything Algorithm 1 needs — plus
+    ``sum``, ``sumsq`` and the int32 ``keep`` count.  Counts are int32, so
+    one segment holds at most 2**31 - 1 elements.
     """
-    if backend == "auto":
-        backend = "numpy" if interpret else "pallas"
-    if backend == "numpy":
-        return _quantile_moments_np(buf, row_seg, row_valid, seg_count,
-                                    density, n_seg=n_seg, nbins=nbins)
-    sweep = (functools.partial(segment_hist_moments_pallas,
-                               interpret=interpret)
-             if backend == "pallas" else
-             functools.partial(_segment_hist_moments_jnp))
+    return _quantile_moments(buf, jnp.asarray(row_seg, jnp.int32),
+                             jnp.asarray(row_valid, jnp.int32),
+                             jnp.asarray(seg_count, jnp.int32),
+                             jnp.asarray(keep_counts(seg_count, density)),
+                             n_seg=n_seg, nbins=nbins)
 
-    n = seg_count.astype(jnp.float32)
-    keep = jnp.maximum(jnp.round(n * density), 1.0).astype(jnp.int32)
 
-    zeros = jnp.zeros((n_seg,), jnp.float32)
-    # The histogram needs a range before it can bin, so the segment max is
-    # computed by a plain fused reduction first (bandwidth-bound, no sort);
-    # the coarse sweep then bins over [0, max_s] and carries the moments.
-    smax = _segment_absmax(buf, row_seg, row_valid, n_seg=n_seg)
-    coarse, ssum, ssq, _, sabs = sweep(buf, row_seg, row_valid, zeros, smax,
-                                       n_seg=n_seg, nbins=nbins)
-    cb = _select_bin(coarse, keep)                             # [S]
-    cw = jnp.maximum(smax, 1e-30) / nbins
-    lo1 = cb.astype(jnp.float32) * cw
-    # rank of the target inside the selected coarse bin
-    suffix = jnp.cumsum(coarse[:, ::-1], axis=1)[:, ::-1]
-    above = jnp.where(cb + 1 < nbins,
-                      jnp.take_along_axis(
-                          jnp.pad(suffix, ((0, 0), (0, 1))),
-                          (cb + 1)[:, None], axis=1)[:, 0],
-                      0)
-    keep_in_bin = jnp.maximum(keep - above, 1)
-
-    refined, _, _, _, _ = sweep(buf, row_seg, row_valid, lo1, cw,
-                                n_seg=n_seg, nbins=nbins,
-                                with_moments=False)
-    rb = _select_bin(refined, keep_in_bin)
-    thr = lo1 + rb.astype(jnp.float32) * (cw / nbins)
-    thr = jnp.where(smax > 0.0, thr, 0.0)
-
-    mean = ssum / jnp.maximum(n, 1.0)
-    var = jnp.maximum(ssq / jnp.maximum(n, 1.0) - mean * mean, 0.0)
-    return {"threshold": thr, "mean": mean, "std": jnp.sqrt(var),
-            "mean_abs": sabs / jnp.maximum(n, 1.0), "max": smax,
-            "sum": ssum, "sumsq": ssq, "keep": keep}
+def keep_counts(seg_count, density) -> np.ndarray:
+    """Algorithm 1's ``k`` per segment: round(n * density), at least 1."""
+    n = np.asarray(seg_count, np.float64)
+    return np.maximum(np.round(n * density), 1.0).astype(np.int32)

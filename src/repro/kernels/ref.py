@@ -86,10 +86,3 @@ def ternary_matmul_grouped_ref(x, pos, neg, scales, expert_idx,
     for e in range(E):
         srow += jnp.where(eid == e, scales[e].astype(jnp.float32), 0.0)
     return acc * srow
-
-
-def popcount_dot_ref(a_pos, a_neg, b_pos, b_neg):
-    n = a_pos.shape[0] * LANE
-    a = dense_of_planes(a_pos[None], a_neg[None], n)[0]
-    b = dense_of_planes(b_pos[None], b_neg[None], n)[0]
-    return jnp.sum(a * b).astype(jnp.int32)

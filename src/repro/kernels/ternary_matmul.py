@@ -1,34 +1,41 @@
 """Pallas TPU kernels: dense × packed-ternary matmul.
 
-Single-expert form:
-
-    y[M, N] = scale * ( x[M, K] @ (pos - neg)[K, N] )
-
-with the ternary matrix stored as two uint32 bitplanes packed along the
-*output* dim (C-order of a [K, N] weight): planes have shape [K, N//32].
-
 Grouped (per-row-expert) form — the zero-merge serving hot path:
 
     y[m, :] = scale[e(m)] * ( x[m, :] @ T_{e(m)} )
 
-with E experts' planes stacked as [E, K, N//32] and a per-row ``expert_idx``
-vector.  One launch contracts a decode batch that mixes experts against all
-resident ternary deltas; the caller adds ``x @ W_base`` (the base weights
-are never re-materialised per expert, and the experts are never merged).
-``transpose_rhs=True`` takes planes packed along the *contraction* dim
-([N, K//32], e.g. an embedding table reused as a tied LM head) and computes
-``x @ T^t`` without repacking.
+with E experts' planes stacked as [E, K, N//32] (two uint32 bitplanes
+packed along the *output* dim, C-order of a [K, N] weight) and a per-row
+``expert_idx`` vector.  One launch contracts a decode batch that mixes
+experts against all resident ternary deltas; the caller adds
+``x @ W_base`` (the base weights are never re-materialised per expert, and
+the experts are never merged).  ``transpose_rhs=True`` takes planes packed
+along the *contraction* dim ([N, K//32], e.g. an embedding table reused as
+a tied LM head) and computes ``x @ T^t`` without repacking.  The
+single-expert :func:`ternary_matmul` is the E=1 case.
 
 TPU adaptation of the paper's §2.2 "binary vector" computation: the ternary
 delta streams HBM→VMEM at 2 bits/param (16x less bandwidth than bf16), is
-unpacked to ±1 tiles in-register, and contracts on the MXU.  Decode-time
-expert application is memory-bound, so the bandwidth saving is the win;
-the unpack ALU work rides free under the matmul.  In the grouped kernel the
-per-expert row masks cost E small VPU selects per tile; each expert's
-contribution still contracts on the MXU.
+unpacked to ±1 tiles in-register, and contracts on the MXU.
 
-Grid: (M/BM, N/BN, K/BK), K innermost for accumulation in the VMEM output
-block.  Block shapes keep the MXU dims at 128 multiples.
+Bit order.  Bit b of plane word w is column 32w + b.  Spreading each
+word's 32 bits over 32 adjacent lanes is a lane interleave the TPU has no
+cheap instruction for, so the kernels never build the natural-order tile.
+Instead bit b of a whole [rows, words] tile is one lane-dense ±1 slab
+(shift + mask on int32), and the interleave moves to the small operand:
+
+* planes packed along N: slab b holds output columns {32w + b}; the
+  kernel computes them as slab @ x^T (the plane tile streams through the
+  MXU against the small x tile) into a bit-major [32, N//32, M] result
+  that the wrapper transposes back (an activation, not the planes);
+* planes packed along K (``transpose_rhs``): slab b contracts against
+  x columns {32w + b}, so the wrapper feeds x bit-major as [32, M, K//32]
+  and the kernel sums x_b @ slab_b over the 32 bits.
+
+The stored plane layout is the wire layout; nothing is rearranged per
+step.  Grid: (M/BM, N/BN, K/BK) with K innermost, accumulating in the VMEM
+output block; blocks follow the (8, 128) tiling rules of
+:mod:`repro.kernels.tpu_params`.
 """
 
 from __future__ import annotations
@@ -37,112 +44,96 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 
-from repro.kernels.tpu_params import (grouped_matmul_cost, lane_block,
-                                      matmul_cost, tpu_compiler_params)
+from repro.kernels.tpu_params import (divisor_block, grouped_matmul_cost,
+                                      lane_block, sublane_block,
+                                      tpu_compiler_params)
 
 LANE = 32
+_HI = lax.Precision.HIGHEST
 
 
-def _unpack_tile(pw, nw, dtype=jnp.int8):
-    """[BK, W] uint32 plane pair -> [BK, W*32] ±1 tile."""
-    shifts = jnp.arange(LANE, dtype=jnp.uint32)[None, None, :]
-    pb = ((pw[:, :, None] >> shifts) & jnp.uint32(1)).astype(dtype)
-    nb = ((nw[:, :, None] >> shifts) & jnp.uint32(1)).astype(dtype)
-    return (pb - nb).reshape(pw.shape[0], pw.shape[1] * LANE)
+def plane_signs(pos_words, neg_words, b) -> jax.Array:
+    """Bit ``b`` of a uint32 plane-word tile pair as an f32 ±1/0 slab.
+
+    Shifts and masks run on int32 (the planes are bitcast, not converted),
+    which is what the TPU vector unit lowers."""
+    p = lax.bitcast_convert_type(pos_words, jnp.int32)
+    n = lax.bitcast_convert_type(neg_words, jnp.int32)
+    sh = jnp.full(p.shape, b, jnp.int32)
+    d = (lax.shift_right_logical(p, sh) & 1) - (lax.shift_right_logical(n, sh)
+                                                & 1)
+    return d.astype(jnp.float32)
 
 
-def _kernel(x_ref, pos_ref, neg_ref, scale_ref, o_ref, *, n_k: int):
+def _row_scale(eid, scales_ref, n_e: int):
+    """Per-row scale ``scales[e(m)]`` (0 for rows outside [0, E))."""
+    s = jnp.zeros(eid.shape, jnp.float32)
+    for e in range(n_e):
+        s += jnp.where(eid == e, scales_ref[e:e + 1, :], 0.0)
+    return s
+
+
+def _kernel_cols(xt_ref, pos_ref, neg_ref, scales_ref, eid_ref, o_ref, *,
+                 n_k: int, n_e: int):
+    """Planes packed along N, word-major.  xt [BK, BM] (x transposed);
+    planes [E, BW, BK]; eid [1, BM]; o [32, BW, BM] (bit-major y^T)."""
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    xb = x_ref[...]                                   # [BM, BK]
-    w = _unpack_tile(pos_ref[...], neg_ref[...])      # [BK, BN]
-    acc = jnp.dot(xb.astype(jnp.float32), w.astype(jnp.float32),
-                  preferred_element_type=jnp.float32)
-    o_ref[...] += acc
+    xt = xt_ref[...].astype(jnp.float32)
+    eid = eid_ref[...]
+    xs = [xt * (eid == e).astype(jnp.float32) for e in range(n_e)]
+
+    def bit(b, carry):
+        acc = jnp.zeros(o_ref.shape[1:], jnp.float32)
+        for e in range(n_e):              # static unroll over E
+            t = plane_signs(pos_ref[e], neg_ref[e], b)      # [BW, BK]
+            acc += jnp.dot(t, xs[e], precision=_HI,
+                           preferred_element_type=jnp.float32)
+        o_ref[b] += acc
+        return carry
+
+    lax.fori_loop(0, LANE, bit, 0)
 
     @pl.when(k == n_k - 1)
     def _scale():
-        o_ref[...] *= scale_ref[0, 0]
+        o_ref[...] *= _row_scale(eid, scales_ref, n_e)[None]
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def ternary_matmul(x: jax.Array, pos: jax.Array, neg: jax.Array,
-                   scale: jax.Array, *, bm: int = 128, bn: int = 128,
-                   bk: int = 128, interpret: bool = True) -> jax.Array:
-    """x: [M, K] float; pos/neg: [K, N//32] uint32; scale: scalar f32.
-    Returns [M, N] f32."""
-    M, K = x.shape
-    Kp, Wn = pos.shape
-    assert Kp == K, (Kp, K)
-    N = Wn * LANE
-
-    bm = min(bm, M)
-    bk = min(bk, K)
-    bn = lane_block(bn, N)
-    pad_m, pad_k, pad_n = (-M) % bm, (-K) % bk, (-N) % bn
-    if pad_m or pad_k:
-        x = jnp.pad(x, ((0, pad_m), (0, pad_k)))
-    if pad_k or pad_n:
-        pos = jnp.pad(pos, ((0, pad_k), (0, pad_n // LANE)))
-        neg = jnp.pad(neg, ((0, pad_k), (0, pad_n // LANE)))
-    Mp, Kpd, Np = M + pad_m, K + pad_k, N + pad_n
-    n_k = Kpd // bk
-
-    grid = (Mp // bm, Np // bn, n_k)
-    out = pl.pallas_call(
-        functools.partial(_kernel, n_k=n_k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn // LANE), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bk, bn // LANE), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-        # i/j tiles are independent; k accumulates into the output block
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary"), interpret=interpret),
-        cost_estimate=matmul_cost(Mp, Np, Kpd,
-                                  elem_bytes=x.dtype.itemsize),
-        interpret=interpret,
-    )(x, pos, neg, scale.reshape(1, 1).astype(jnp.float32))
-    return out[:M, :N]
-
-
-def _kernel_grouped(x_ref, pos_ref, neg_ref, scales_ref, eid_ref, o_ref, *,
-                    n_k: int, n_e: int, transpose_rhs: bool):
+def _kernel_rows(x_ref, pos_ref, neg_ref, scales_ref, eid_ref, o_ref, *,
+                 n_k: int, n_e: int):
+    """Planes packed along K, word-major.  x [32, BM, BW] (bit-major);
+    planes [E, BW, BN]; eid [BM, 1]; o [BM, BN]."""
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    xb = x_ref[...].astype(jnp.float32)               # [BM, BK]
-    eid = eid_ref[...]                                # [BM, 1] int32
-    acc = jnp.zeros_like(o_ref)
-    for e in range(n_e):                              # static unroll over E
-        w = _unpack_tile(pos_ref[e], neg_ref[e]).astype(jnp.float32)
-        if transpose_rhs:                             # w: [BN, BK] -> use w^t
-            w = w.T
-        sel = (eid == e).astype(jnp.float32)          # [BM, 1] row mask
-        acc += jnp.dot(xb * sel, w, preferred_element_type=jnp.float32)
-    o_ref[...] += acc
+    eid = eid_ref[...]
+    sel = [(eid == e).astype(jnp.float32) for e in range(n_e)]
+
+    def bit(b, carry):
+        xb = x_ref[b].astype(jnp.float32)             # [BM, BW]
+        acc = jnp.zeros(o_ref.shape, jnp.float32)
+        for e in range(n_e):
+            t = plane_signs(pos_ref[e], neg_ref[e], b)   # [BW, BN]
+            acc += jnp.dot(xb * sel[e], t, precision=_HI,
+                           preferred_element_type=jnp.float32)
+        o_ref[...] += acc
+        return carry
+
+    lax.fori_loop(0, LANE, bit, 0)
 
     @pl.when(k == n_k - 1)
     def _scale():
-        eid_f = eid_ref[...]
-        srow = jnp.zeros_like(eid_f, dtype=jnp.float32)
-        for e in range(n_e):                          # per-row scale gather
-            srow += jnp.where(eid_f == e, scales_ref[e, 0], 0.0)
-        o_ref[...] *= srow
+        o_ref[...] *= _row_scale(eid, scales_ref, n_e)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret",
@@ -150,75 +141,121 @@ def _kernel_grouped(x_ref, pos_ref, neg_ref, scales_ref, eid_ref, o_ref, *,
 def ternary_matmul_grouped(x: jax.Array, pos: jax.Array, neg: jax.Array,
                            scales: jax.Array, expert_idx: jax.Array, *,
                            transpose_rhs: bool = False, bm: int = 128,
-                           bn: int = 128, bk: int = 128,
+                           bn: int = 1024, bk: int = 512,
                            interpret: bool = True) -> jax.Array:
     """Per-row-expert delta contraction over stacked planes, one launch.
 
-    x: [M, K] float; pos/neg: [E, K, N//32] uint32 ([E, N, K//32] when
+    x: [M, K] float; pos/neg: [E, K, N//32] uint32 ([E, N, ceil(K/32)] when
     ``transpose_rhs``); scales: [E] f32; expert_idx: [M] int32 in [0, E)
     (-1 rows get a zero delta).  Returns [M, N] f32 with
-    ``y[m] = scales[expert_idx[m]] * (x[m] @ T_{expert_idx[m]})`` — row-wise
-    bit-identical to running :func:`ternary_matmul` per expert with the same
-    block shapes and selecting rows.
+    ``y[m] = scales[expert_idx[m]] * (x[m] @ T_{expert_idx[m]})``.
+
+    ``bm``/``bn``/``bk`` are upper bounds in elements; the kernel picks the
+    legal TPU blocks under them (a contraction block always divides K).
+    Rows and output columns are independent, so mixed-expert rows are
+    bitwise what single-expert runs produce.
+
+    The kernel reads the planes word-major ([E, words, K] / [E, words, N]).
+    A TPU stores a plane stack with a narrow word dimension in exactly that
+    order (the word dim is not the minor one in its default layout), so the
+    swap below is a relabelling, not a copy.
     """
     M, K = x.shape
     E = pos.shape[0]
-    if transpose_rhs:
-        N, Wk = pos.shape[1], pos.shape[2]
-        assert Wk == -(-K // LANE), (pos.shape, K)
-    else:
-        Kp, Wn = pos.shape[1], pos.shape[2]
-        assert Kp == K, (pos.shape, K)
-        N = Wn * LANE
     assert scales.shape == (E,), scales.shape
     assert expert_idx.shape == (M,), (expert_idx.shape, M)
-
-    bm = min(bm, M)
-    bk = lane_block(bk, K) if transpose_rhs else min(bk, K)
-    bn = min(bn, N) if transpose_rhs else lane_block(bn, N)
-    pad_m, pad_k, pad_n = (-M) % bm, (-K) % bk, (-N) % bn
-    if pad_m or pad_k:
-        x = jnp.pad(x, ((0, pad_m), (0, pad_k)))
-    if pad_m:
-        expert_idx = jnp.pad(expert_idx, (0, pad_m), constant_values=-1)
+    eid = expert_idx.astype(jnp.int32)
+    scales2 = scales.reshape(E, 1).astype(jnp.float32)
+    pos_w, neg_w = jnp.swapaxes(pos, 1, 2), jnp.swapaxes(neg, 1, 2)
     if transpose_rhs:
-        pad_w = (K + pad_k) // LANE - pos.shape[2]
-        if pad_n or pad_w:
-            pos = jnp.pad(pos, ((0, 0), (0, pad_n), (0, pad_w)))
-            neg = jnp.pad(neg, ((0, 0), (0, pad_n), (0, pad_w)))
-    else:
-        if pad_k or pad_n:
-            pos = jnp.pad(pos, ((0, 0), (0, pad_k), (0, pad_n // LANE)))
-            neg = jnp.pad(neg, ((0, 0), (0, pad_k), (0, pad_n // LANE)))
-    Mp, Kpd, Np = M + pad_m, K + pad_k, N + pad_n
-    n_k = Kpd // bk
+        return _grouped_rows(x, pos_w, neg_w, scales2, eid, bm=bm, bn=bn,
+                             bk=bk, interpret=interpret)
+    return _grouped_cols(x, pos_w, neg_w, scales2, eid, bm=bm, bn=bn, bk=bk,
+                         interpret=interpret)
 
-    if transpose_rhs:
-        plane_block = (E, bn, bk // LANE)
-        plane_map = lambda i, j, k: (0, j, k)  # noqa: E731
-    else:
-        plane_block = (E, bk, bn // LANE)
-        plane_map = lambda i, j, k: (0, k, j)  # noqa: E731
 
-    grid = (Mp // bm, Np // bn, n_k)
+def _grouped_cols(x, pos, neg, scales2, eid, *, bm, bn, bk, interpret):
+    """pos/neg word-major [E, N//32, K]."""
+    M, K = x.shape
+    E, Wn, Kp = pos.shape
+    assert Kp == K, (pos.shape, K)
+    bm = lane_block(bm, M)
+    bk = divisor_block(bk, K)
+    bw = sublane_block(max(bn // LANE, 1), Wn)
+    pad_m = (-M) % bm
+    if pad_m:                          # activations only; planes never copy
+        x = jnp.pad(x, ((0, pad_m), (0, 0)))
+        eid = jnp.pad(eid, (0, pad_m), constant_values=-1)
+    Mp = M + pad_m
+    n_k = K // bk
     out = pl.pallas_call(
-        functools.partial(_kernel_grouped, n_k=n_k, n_e=E,
-                          transpose_rhs=transpose_rhs),
-        grid=grid,
+        functools.partial(_kernel_cols, n_k=n_k, n_e=E),
+        grid=(Mp // bm, pl.cdiv(Wn, bw), n_k),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec(plane_block, plane_map),
-            pl.BlockSpec(plane_block, plane_map),
+            pl.BlockSpec((bk, bm), lambda i, j, k: (k, i)),
+            pl.BlockSpec((E, bw, bk), lambda i, j, k: (0, j, k)),
+            pl.BlockSpec((E, bw, bk), lambda i, j, k: (0, j, k)),
+            pl.BlockSpec((E, 1), lambda i, j, k: (0, 0)),
+            pl.BlockSpec((1, bm), lambda i, j, k: (0, i)),
+        ],
+        out_specs=pl.BlockSpec((LANE, bw, bm), lambda i, j, k: (0, j, i)),
+        out_shape=jax.ShapeDtypeStruct((LANE, Wn, Mp), jnp.float32),
+        # i/j tiles are independent; k accumulates into the output block
+        compiler_params=tpu_compiler_params(
+            ("parallel", "parallel", "arbitrary"), interpret=interpret),
+        cost_estimate=grouped_matmul_cost(Mp, Wn * LANE, K, E,
+                                          elem_bytes=x.dtype.itemsize),
+        interpret=interpret,
+    )(x.T, pos, neg, scales2, eid.reshape(1, -1))
+    # o[b, w, m] = y[m, 32w + b]
+    return jnp.transpose(out, (2, 1, 0)).reshape(Mp, Wn * LANE)[:M]
+
+
+def _grouped_rows(x, pos, neg, scales2, eid, *, bm, bn, bk, interpret):
+    """pos/neg word-major [E, ceil(K/32), N]."""
+    M, K = x.shape
+    E, Wk, N = pos.shape
+    assert Wk == -(-K // LANE), (pos.shape, K)
+    bm = sublane_block(bm, M)
+    bn = lane_block(bn, N)
+    bw = divisor_block(max(bk // LANE, 1), Wk)
+    pad_m = (-M) % bm
+    x = jnp.pad(x, ((0, pad_m), (0, Wk * LANE - K)))
+    if pad_m:
+        eid = jnp.pad(eid, (0, pad_m), constant_values=-1)
+    Mp = M + pad_m
+    # x[m, 32w + b] -> xb[b, m, w]: bit-major (activations only)
+    xb = jnp.transpose(x.reshape(Mp, Wk, LANE), (2, 0, 1))
+    n_k = Wk // bw
+    out = pl.pallas_call(
+        functools.partial(_kernel_rows, n_k=n_k, n_e=E),
+        grid=(Mp // bm, pl.cdiv(N, bn), n_k),
+        in_specs=[
+            pl.BlockSpec((LANE, bm, bw), lambda i, j, k: (0, i, k)),
+            pl.BlockSpec((E, bw, bn), lambda i, j, k: (0, k, j)),
+            pl.BlockSpec((E, bw, bn), lambda i, j, k: (0, k, j)),
             pl.BlockSpec((E, 1), lambda i, j, k: (0, 0)),
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((Mp, N), jnp.float32),
         compiler_params=tpu_compiler_params(
             ("parallel", "parallel", "arbitrary"), interpret=interpret),
-        cost_estimate=grouped_matmul_cost(Mp, Np, Kpd, E,
+        cost_estimate=grouped_matmul_cost(Mp, N, Wk * LANE, E,
                                           elem_bytes=x.dtype.itemsize),
         interpret=interpret,
-    )(x, pos, neg, scales.reshape(-1, 1).astype(jnp.float32),
-      expert_idx.reshape(-1, 1).astype(jnp.int32))
-    return out[:M, :N]
+    )(xb, pos, neg, scales2, eid.reshape(-1, 1))
+    return out[:M]
+
+
+def ternary_matmul(x: jax.Array, pos: jax.Array, neg: jax.Array,
+                   scale: jax.Array, *, bm: int = 128, bn: int = 1024,
+                   bk: int = 512, interpret: bool = True) -> jax.Array:
+    """x: [M, K] float; pos/neg: [K, N//32] uint32; scale: scalar f32.
+    Returns ``scale * (x @ T)`` as [M, N] f32 — the one-expert grouped
+    kernel."""
+    M = x.shape[0]
+    return ternary_matmul_grouped(
+        x, pos[None], neg[None], jnp.reshape(scale, (1,)),
+        jnp.zeros((M,), jnp.int32), bm=bm, bn=bn, bk=bk,
+        interpret=interpret)

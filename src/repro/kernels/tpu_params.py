@@ -1,50 +1,60 @@
-"""Shared Mosaic compiler hints for the Pallas kernels.
+"""Shared Mosaic tiling rules and compiler hints for the Pallas kernels.
 
 ``dimension_semantics`` tells the TPU lowering which grid dimensions are
 embarrassingly parallel (safe to pipeline/reorder across cores) and which
 carry a sequential accumulation ("arbitrary").  Interpret mode (CPU CI)
 ignores compiler hints, so we return ``None`` there and keep the kernels
 runnable on any backend.
+
+Block shapes follow the TPU's (8, 128) tiling: the last dimension of every
+block is a multiple of 128 lanes or the whole array dimension, and the
+second-to-last a multiple of 8 sublanes or the whole dimension.  Blocks
+along independent output dimensions may overhang the array (Pallas masks
+the edge block); blocks along a contraction must divide it exactly.
 """
 
 from __future__ import annotations
 
 from jax.experimental import pallas as pl
 
-LANE = 32   # uint32 bit lanes (TPU VPU native word)
+LANE = 32       # bits per uint32 plane word
+LANES = 128     # TPU vector lanes (last-dim tile)
+SUBLANES = 8    # TPU sublanes (second-to-last-dim tile)
 
 
 def lane_block(b: int, n: int) -> int:
-    """Clamp a block width to [LANE, ~n] while keeping it a LANE multiple.
+    """Block along a lane (last) dimension of extent ``n``: the whole
+    dimension when ``b`` covers it, else ``b`` rounded down to a multiple
+    of 128 (at least 128).  Edge blocks may overhang ``n``."""
+    if n <= b:
+        return n
+    return max(LANES, (b // LANES) * LANES)
 
-    ``min(b, n)`` alone breaks the ``% LANE`` contract whenever n (or the
-    caller's b) is not a multiple of 32 — the small-shape bug; the floor at
-    one lane keeps tiny-N inputs legal (they pad up to one word).  Shared
-    by every kernel that tiles a packed-plane dimension.
-    """
-    return max(LANE, (min(b, n) // LANE) * LANE)
+
+def sublane_block(b: int, n: int) -> int:
+    """Block along a second-to-last dimension: whole, or a multiple of 8."""
+    if n <= b:
+        return n
+    return max(SUBLANES, (b // SUBLANES) * SUBLANES)
+
+
+def divisor_block(b: int, n: int, align: int = LANES) -> int:
+    """Largest multiple of ``align`` that is <= ``b`` and divides ``n``
+    (a contraction block must tile it exactly); the whole ``n`` when none
+    does."""
+    for cand in range((min(b, n) // align) * align, 0, -align):
+        if n % cand == 0:
+            return cand
+    return n
 
 
 def tpu_compiler_params(dimension_semantics: tuple[str, ...], *,
                         interpret: bool = False):
-    """TPUCompilerParams with the given grid semantics, or None off-TPU."""
+    """CompilerParams with the given grid semantics, or None off-TPU."""
     if interpret:
         return None
     from jax.experimental.pallas import tpu as pltpu
-    return pltpu.TPUCompilerParams(dimension_semantics=dimension_semantics)
-
-
-def matmul_cost(m: int, n: int, k: int, *, elem_bytes: int = 4,
-                packed_k_bytes: int | None = None) -> pl.CostEstimate:
-    """CostEstimate for a dense x packed-ternary matmul: FLOPs from the MXU
-    contraction, bytes from x + the 2-bit planes + the f32 output."""
-    plane_bytes = (packed_k_bytes if packed_k_bytes is not None
-                   else 2 * (k * n // 8))          # two planes, 1 bit each
-    return pl.CostEstimate(
-        flops=2 * m * n * k,
-        bytes_accessed=m * k * elem_bytes + plane_bytes + m * n * 4,
-        transcendentals=0,
-    )
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
 def streaming_cost(n_elems: int, *, in_bytes_per_elem: float,

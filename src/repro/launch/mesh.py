@@ -11,7 +11,21 @@ def make_production_mesh(*, multi_pod: bool = False):
 
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    The installed JAX makes explicit-axis meshes by default, under which
+    every gather needs an ``out_sharding``; the sharding rules here place
+    arrays with ``NamedSharding`` and let the partitioner propagate, which
+    is what Auto axes mean."""
+    import jax
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 SERVE_AXES = ("expert", "model")
@@ -40,11 +54,25 @@ def make_serve_mesh(shape=(1, 1)):
         raise ValueError(
             f"serve mesh {shape} needs {n} devices but only {avail} are "
             "visible (set --xla_force_host_platform_device_count for CPU)")
-    return jax.make_mesh(tuple(shape), SERVE_AXES)
+    return auto_mesh(shape, SERVE_AXES)
 
 
-# TPU v5e hardware constants used by the roofline (benchmarks/roofline.py)
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link
-CHIPS_PER_POD = 256
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+# of inter-chip interconnect (four links of 50 GB/s).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"peak_flops_bf16": 197e12, "peak_ops_int8": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bw": 819e9,
+                    "ici_bw": 200e9, "ici_link_bw": 50e9},
+}
+
+
+def device_peaks(kind: str) -> dict:
+    """Peaks of one chip of ``kind``; a kind not in the table is an error,
+    never a default."""
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {kind!r}; "
+                       f"known: {sorted(DEVICE_PEAKS)}") from None

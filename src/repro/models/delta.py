@@ -60,6 +60,10 @@ class MatmulDelta:
     ``transpose``); ``scales``: f32 [(U,) E].  The optional leading unit
     axis is stripped by the model's unit scan.
 
+    ``mesh``: the serving mesh when the stacks are expert-parallel (the
+    Pallas kernel then runs per ``expert`` shard); static, like the
+    shapes.
+
     ``dense``: optional f32 sign stack [(U,) E, K, N] (unscaled).  On TPU
     it stays None — the grouped Pallas kernel unpacks the 2-bit planes
     in-register under the MXU contraction, so HBM traffic is the packed
@@ -74,22 +78,29 @@ class MatmulDelta:
     n_out: int = 0
     transpose: bool = False
     dense: Optional[jax.Array] = None
+    mesh: Any = None          # serving mesh of expert-parallel stacks
 
     def tree_flatten(self):
         return ((self.pos, self.neg, self.scales, self.dense),
-                (self.n_out, self.transpose))
+                (self.n_out, self.transpose, self.mesh))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         pos, neg, scales, dense = children
         return cls(pos=pos, neg=neg, scales=scales, n_out=aux[0],
-                   transpose=aux[1], dense=dense)
+                   transpose=aux[1], dense=dense, mesh=aux[2])
 
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class EmbedDelta:
     """Stacked planes of the embedding table [V, d] (d % 32 == 0).
+
+    ``pos``/``neg`` [E, V, d//32] feed the tied-head grouped kernel; a TPU
+    stores them vocab-minor.  ``rows_pos``/``rows_neg`` [E, V*d//32] are
+    the same words as the flat stacked buffers (the cache tier's, shared,
+    not copied): a token's row is 32-bit-contiguous there, so the per-token
+    embedding gather slices it without relaying out the table.
 
     ``dense``: optional f32 sign stack [E, V, d] (unscaled), materialised
     off-TPU exactly like :class:`MatmulDelta`.
@@ -98,14 +109,18 @@ class EmbedDelta:
     pos: jax.Array      # [E, V, d//32]
     neg: jax.Array
     scales: jax.Array   # [E]
+    rows_pos: jax.Array   # [E, V * d//32]
+    rows_neg: jax.Array
     dense: Optional[jax.Array] = None
+    mesh: Any = None
 
     def tree_flatten(self):
-        return (self.pos, self.neg, self.scales, self.dense), ()
+        return (self.pos, self.neg, self.scales, self.rows_pos,
+                self.rows_neg, self.dense), (self.mesh,)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children)
+        return cls(*children, mesh=aux[0])
 
 
 @jax.tree_util.register_pytree_node_class
@@ -152,7 +167,8 @@ def delta_proj(x: jax.Array, md: Optional[MatmulDelta],
     else:
         from repro.kernels.ops import grouped_delta_matmul
         d = grouped_delta_matmul(rows, md.pos, md.neg, md.scales, eid_rows,
-                                 transpose_rhs=md.transpose, n_out=md.n_out)
+                                 transpose_rhs=md.transpose, n_out=md.n_out,
+                                 mesh=md.mesh)
     return d.reshape(B, T, md.n_out)
 
 
@@ -186,14 +202,31 @@ def embed_delta_rows(ed: Optional[EmbedDelta], tokens: jax.Array,
     e = eid.astype(jnp.int32)[:, None]                       # [B, 1]
     if ed.dense is not None:
         delta = ed.dense[e, tokens]                          # [B, T, d]
-    else:
-        pw = ed.pos[e, tokens]                               # [B, T, W]
-        nw = ed.neg[e, tokens]
+        return delta * ed.scales[e][..., None]
+    W = ed.pos.shape[-1]
+
+    def row(flat, ei, t):                                    # [W] words
+        return jax.lax.dynamic_slice(flat, (jnp.maximum(ei, 0), t * W),
+                                     (1, W))[0]
+
+    take = jax.vmap(jax.vmap(row, (None, None, 0)), (None, 0, 0))
+
+    def rows(tok, ei, rows_pos, rows_neg, scales):
+        """Scaled ±1 rows of each token's expert; 0 where ``ei`` < 0."""
+        pw = take(rows_pos, ei, tok)                         # [B, T, W]
+        nw = take(rows_neg, ei, tok)
         shifts = jnp.arange(LANE, dtype=jnp.uint32)
         pb = ((pw[..., None] >> shifts) & jnp.uint32(1)).astype(jnp.float32)
         nb = ((nw[..., None] >> shifts) & jnp.uint32(1)).astype(jnp.float32)
-        delta = (pb - nb).reshape(pw.shape[:2] + (-1,))[..., :d_model]
-    return delta * ed.scales[e][..., None]
+        d = (pb - nb).reshape(pw.shape[:2] + (-1,))[..., :d_model]
+        s = jnp.where(ei >= 0, scales[jnp.maximum(ei, 0)], 0.0)
+        return d * s[:, None, None]
+
+    if ed.mesh is not None:
+        from repro.kernels.ops import expert_parallel
+        rows = expert_parallel(rows, ed.mesh)
+    return rows(tokens.astype(jnp.int32), e[:, 0], ed.rows_pos,
+                ed.rows_neg, ed.scales)
 
 
 def tied_logits_delta(x: jax.Array, ed: Optional[EmbedDelta],
@@ -202,7 +235,7 @@ def tied_logits_delta(x: jax.Array, ed: Optional[EmbedDelta],
     if ed is None or eid is None:
         return None
     md = MatmulDelta(pos=ed.pos, neg=ed.neg, scales=ed.scales, n_out=vocab,
-                     transpose=True, dense=ed.dense)
+                     transpose=True, dense=ed.dense, mesh=ed.mesh)
     return delta_proj(x, md, eid)
 
 
@@ -286,7 +319,8 @@ def _dense_values(pos: jax.Array, neg: jax.Array, scales: jax.Array,
 
 
 def build_overlay(plan: dict, stacks: dict,
-                  materialize: Optional[bool] = None) -> Optional[dict]:
+                  materialize: Optional[bool] = None,
+                  mesh=None) -> Optional[dict]:
     """Shape the cache tier's stacked plane buffers into an overlay tree.
 
     ``stacks`` is {path: (pos [E, W], neg [E, W], scales [E], shape)} as
@@ -299,7 +333,9 @@ def build_overlay(plan: dict, stacks: dict,
     ``materialize`` (default: off-TPU) additionally unpacks each projection
     stack to dense f32 signs once, so the jnp serve path pays zero
     per-step unpacking; on TPU the planes stay packed for the Pallas
-    kernels.
+    kernels.  ``mesh`` marks stacks that are expert-parallel on a
+    serving mesh (``DeviceCache(mesh=...)``), so the packed branches run
+    per ``expert`` shard.
     """
     if materialize is None:
         materialize = jax.default_backend() != "tpu"
@@ -326,7 +362,8 @@ def build_overlay(plan: dict, stacks: dict,
                      if materialize else None)
             entry = EmbedDelta(pos=pos.reshape(E, V, d // LANE),
                                neg=neg.reshape(E, V, d // LANE),
-                               scales=scales, dense=dense)
+                               scales=scales, rows_pos=pos, rows_neg=neg,
+                               dense=dense, mesh=mesh)
         else:                                                    # matmul
             U = max(spec.units, 1)
             shape = (E, U, spec.k, spec.n // LANE)
@@ -340,13 +377,14 @@ def build_overlay(plan: dict, stacks: dict,
                     scales=jnp.broadcast_to(scales[None], (spec.units, E)),
                     n_out=spec.n,
                     dense=(jnp.swapaxes(dense, 0, 1)
-                           if dense is not None else None))
+                           if dense is not None else None), mesh=mesh)
             else:
                 entry = MatmulDelta(pos=pos.reshape(shape)[:, 0],
                                     neg=neg.reshape(shape)[:, 0],
                                     scales=scales, n_out=spec.n,
                                     dense=(dense[:, 0]
-                                           if dense is not None else None))
+                                           if dense is not None else None),
+                                    mesh=mesh)
         node = overlay
         parts = path.split("/")
         for p in parts[:-1]:

@@ -293,7 +293,7 @@ class ServeEngine:
                 return self._overlays[experts]
             del self._overlays[experts]
         stacks = self.cache.stacked(experts)
-        overlay = build_overlay(self._plan, stacks)
+        overlay = build_overlay(self._plan, stacks, mesh=self.mesh)
         if overlay is not None:
             while len(self._overlays) >= DeviceCache.MAX_STACKS:
                 self._overlays.pop(next(iter(self._overlays)))

@@ -617,8 +617,14 @@ class DeviceCache:
             self._observe_promotion(time.monotonic() - t0)
         self._sync_remote_stats()
         self.stats.store_to_host_bytes += self.store.nbytes(name)
+        # with a mesh the packed tree is replicated (the staging tier every
+        # shard pays in full), never parked on the first device alone
+        target = None
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            target = NamedSharding(self.mesh, PartitionSpec())
         packed = jax.tree_util.tree_map(
-            jax.device_put, host_packed,
+            lambda x: jax.device_put(x, target), host_packed,
             is_leaf=lambda x: hasattr(x, "pos"))
         size = tree_packed_bytes(packed)
         while self._cache and (self.shard_resident_bytes() + size

@@ -149,24 +149,22 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_blocks: int,
     """
     dtype = dtype or dtype_of(cfg)
 
-    def place(z):
+    def pool(shape):
+        # with a mesh the pool is born sharded: no full copy on device 0
         if mesh is None:
-            return z
+            return jnp.zeros(shape, dtype)
         from repro.distributed.sharding import serve_kv_sharding
-        return jax.device_put(
-            z, serve_kv_sharding(mesh, tuple(z.shape), layout="paged"))
+        return jnp.zeros(shape, dtype, device=serve_kv_sharding(
+            mesh, shape, layout="paged"))
 
     layers = {}
     for i, b in enumerate(cfg.pattern):
         if b.kind != "attn":
             raise ValueError("paged KV covers pure-attention patterns only; "
                              f"block {i} is {b.kind!r}")
-        layers[f"block{i}"] = {
-            "k": place(jnp.zeros((cfg.n_units, n_blocks, block_size,
-                                  b.attn.n_kv, b.attn.head_dim), dtype)),
-            "v": place(jnp.zeros((cfg.n_units, n_blocks, block_size,
-                                  b.attn.n_kv, b.attn.head_dim), dtype)),
-        }
+        shape = (cfg.n_units, n_blocks, block_size, b.attn.n_kv,
+                 b.attn.head_dim)
+        layers[f"block{i}"] = {"k": pool(shape), "v": pool(shape)}
     return {
         "layers": layers,
         "tables": jnp.full((batch, max_blocks), -1, jnp.int32),

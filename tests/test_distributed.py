@@ -67,7 +67,8 @@ TRAIN_SHARDED = textwrap.dedent("""
     from repro.data.pipeline import make_batch_for
     from repro.core.gradient_compression import GradCompressionConfig
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_smoke_config("qwen2_5_3b")
     api = build(cfg)
     rt = Runtime(shard=make_shard_fn(mesh, cfg), attn_chunk_q=16,
@@ -112,7 +113,8 @@ SP_DECODE = textwrap.dedent("""
     from repro.distributed.sharding import make_shard_fn
     from repro.distributed.collectives import make_sp_decode_attn
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((2, 4), ("data", "model"))
     cfg = get_smoke_config("qwen2_5_3b", n_units=2)
     api = build(cfg)
     rt_local = Runtime(attn_chunk_q=16, attn_chunk_k=16, remat_policy="none")

@@ -87,7 +87,8 @@ SHARD_MAP_SCRIPT = textwrap.dedent("""
     from repro.core.gradient_compression import (
         GradCompressionConfig, compressed_cross_pod_mean, init_error_state)
 
-    mesh = jax.make_mesh((4,), ("pod",))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((4,), ("pod",))
     cfg = GradCompressionConfig(density=0.25)
     rng = np.random.default_rng(0)
     g_all = jnp.asarray(rng.normal(0, 1, (4, 2048)), jnp.float32)

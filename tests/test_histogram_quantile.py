@@ -1,6 +1,7 @@
 """Streaming-compression validation: the O(n) histogram-quantile threshold
-vs ``jnp.quantile``, the Pallas sweep kernel vs the vectorised jnp path, and
-the end-to-end ``compress_packed`` pipeline vs the seed per-leaf path."""
+vs ``jnp.quantile``, the suffix-count search vs an explicit numpy
+histogram of the same bins, and the end-to-end ``compress_packed``
+pipeline vs the seed per-leaf path."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -10,8 +11,6 @@ from repro.core import (CompressionConfig, compress, compress_packed,
                         decompress, pack_tree, unpack_tree)
 from repro.core.compeft import _build_segment_buffer
 from repro.kernels.histogram_quantile import (NBINS,
-                                              _segment_hist_moments_jnp,
-                                              segment_hist_moments_pallas,
                                               segmented_quantile_moments)
 
 DENSITIES = (0.05, 0.1, 0.5)
@@ -95,42 +94,55 @@ def test_all_zero_segment_threshold_is_zero():
     assert float(out["std"][0]) == 0.0
 
 
-def test_pallas_sweep_matches_jnp_sweep():
-    arrays = [v[:4100] for v in _dists().values()]
-    buf, row_seg, row_valid, _, _ = _segbuf(arrays, cols=256)
-    n_seg = len(arrays)
-    lo = jnp.zeros((n_seg,), jnp.float32)
-    width = jnp.asarray([float(np.abs(a).max()) for a in arrays], jnp.float32)
-    jh = _segment_hist_moments_jnp(buf, row_seg, row_valid, lo, width,
-                                   n_seg=n_seg, nbins=256)
-    assert buf.shape[0] % 8 != 0     # exercises the kernel's internal pad
-    ph = segment_hist_moments_pallas(buf, row_seg, row_valid, lo, width,
-                                     n_seg=n_seg, nbins=256, interpret=True)
-    np.testing.assert_array_equal(np.asarray(jh[0]), np.asarray(ph[0]))
-    for a, b in zip(jh[1:], ph[1:]):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-3)
+def _histogram_threshold_np(arrays, density, nbins):
+    """Oracle: the two-pass scheme with explicit np.bincount histograms."""
+    thrs = []
+    for a in arrays:
+        mag = np.abs(a.astype(np.float32))
+        keep = max(int(np.round(a.size * np.float64(density))), 1)
+        smax = np.float32(mag.max())
+        if smax <= 0:
+            thrs.append(0.0)
+            continue
+
+        def suffix(lo, width):
+            w = np.float32(max(width, np.float32(1e-30)))
+            pos = (mag - lo) * np.float32(nbins / w)
+            inr = (mag >= lo) & (mag <= lo + w)
+            b = np.clip(pos.astype(np.int64), 0, nbins - 1)[inr]
+            h = np.bincount(b, minlength=nbins)
+            return np.cumsum(h[::-1])[::-1]
+
+        s1 = suffix(np.float32(0.0), smax)
+        cb = max(int(np.nonzero(s1 >= keep)[0].max(initial=0)), 0)
+        cw = np.float32(max(smax, np.float32(1e-30)) / np.float32(nbins))
+        lo1 = np.float32(cb) * cw
+        above = int(s1[cb + 1]) if cb + 1 < nbins else 0
+        s2 = suffix(lo1, cw)
+        rb = max(int(np.nonzero(s2 >= max(keep - above, 1))[0].max(
+            initial=0)), 0)
+        thrs.append(float(lo1 + np.float32(rb) * (cw / np.float32(nbins))))
+    return np.asarray(thrs, np.float32)
 
 
-@pytest.mark.parametrize("backend", ["numpy", "jnp", "pallas"])
-def test_backends_agree_on_threshold(backend):
-    """All three sweep implementations (incl. the TPU path with a row count
-    that is not a multiple of its block) produce the same threshold."""
+@pytest.mark.parametrize("cols,nbins,density", [(512, 256, 0.1),
+                                                (256, 256, 0.05),
+                                                (512, 2048, 0.1),
+                                                (128, 64, 0.5)])
+def test_threshold_matches_histogram_oracle(cols, nbins, density):
+    """The suffix-count binary search lands on exactly the bins an explicit
+    histogram selects (rows not a multiple of 8, ragged last rows)."""
     rng = np.random.default_rng(3)
     arrays = [rng.normal(0, 1, 4321).astype(np.float32),
-              rng.normal(0, 5, 777).astype(np.float32)]
-    buf, row_seg, row_valid, seg_count, _ = _segbuf(arrays, cols=512)
-    assert buf.shape[0] % 8 != 0
+              rng.normal(0, 5, 777).astype(np.float32),
+              np.where(rng.random(999) < 0.5, 0.1, 10.0).astype(np.float32)]
+    buf, row_seg, row_valid, seg_count, _ = _segbuf(arrays, cols=cols)
     out = segmented_quantile_moments(buf, row_seg, row_valid, seg_count,
-                                     0.1, n_seg=2, nbins=256,
-                                     backend=backend, interpret=True)
-    ref = segmented_quantile_moments(buf, row_seg, row_valid, seg_count,
-                                     0.1, n_seg=2, nbins=256,
-                                     backend="numpy")
-    np.testing.assert_allclose(np.asarray(out["threshold"]),
-                               np.asarray(ref["threshold"]), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(out["std"]),
-                               np.asarray(ref["std"]), rtol=1e-4)
+                                     density, n_seg=len(arrays),
+                                     nbins=nbins)
+    np.testing.assert_array_equal(
+        np.asarray(out["threshold"]),
+        _histogram_threshold_np(arrays, density, nbins))
 
 
 @pytest.mark.parametrize("per_tensor", [True, False])
@@ -172,3 +184,27 @@ def test_compress_packed_roundtrip_decompress():
     assert len(vals) <= 3                      # {-s, 0, +s}
     achieved = float((np.asarray(dense) != 0).mean())
     assert achieved == pytest.approx(0.2, abs=0.02)
+
+
+@pytest.mark.parametrize("shape", [(6, 64, 96), (40, 2048), (3, 5, 77),
+                                   (1000,)])
+def test_leaf_by_leaf_matches_whole_tree(shape):
+    """A one-leaf call takes the leaf's word-aligned last dim as its row
+    width; the bits and thresholds equal the whole-tree call's (rows of
+    ``STREAM_COLS``), and the scales agree to f32 summation order."""
+    rng = np.random.default_rng(9)
+    tau = {"a": jnp.asarray(rng.normal(0, 0.02, shape), jnp.float32),
+           "b": jnp.asarray(rng.standard_t(3, (33, 64)), jnp.bfloat16)}
+    cfg = CompressionConfig(density=0.1, per_tensor=True)
+    tree, tstats = compress_packed(tau, cfg, return_stats=True)
+    for i, k in enumerate(sorted(tau)):
+        one, ostats = compress_packed(tau[k], cfg, return_stats=True)
+        np.testing.assert_array_equal(np.asarray(one.pos),
+                                      np.asarray(tree[k].pos))
+        np.testing.assert_array_equal(np.asarray(one.neg),
+                                      np.asarray(tree[k].neg))
+        assert float(ostats["threshold"][0]) == float(tstats["threshold"][i])
+        np.testing.assert_allclose(float(one.scale), float(tree[k].scale),
+                                   rtol=1e-6)
+        assert one.shape == tree[k].shape
+        assert one.orig_dtype == tree[k].orig_dtype

@@ -18,7 +18,6 @@ from repro.core import CompressionConfig, compress, pack_ternary
 from repro.core.compeft import CompressedTensor
 from repro.kernels import ops, ref
 from repro.kernels.pack import pack_ternary_planes
-from repro.kernels.popcount_dot import popcount_dot
 from repro.kernels.ternary_matmul import ternary_matmul, ternary_matmul_grouped
 from repro.kernels.unpack_add import unpack_add, unpack_add_many
 
@@ -120,27 +119,31 @@ def test_pack_then_matmul_roundtrip():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
-def _popcount_dot_property(seed):
+def _expert_dot_property(seed):
     rng = np.random.default_rng(seed)
-    W = int(rng.integers(1, 40))
-    ap, an = rand_planes(seed, 1, W * LANE)
-    bp, bn = rand_planes(seed + 100, 1, W * LANE)
-    got = popcount_dot(ap.reshape(-1), an.reshape(-1), bp.reshape(-1),
-                       bn.reshape(-1), bw=64, interpret=True)
-    want = ref.popcount_dot_ref(ap.reshape(-1), an.reshape(-1),
-                                bp.reshape(-1), bn.reshape(-1))
-    assert int(got) == int(want)
+    n = int(rng.integers(1, 40)) * LANE - int(rng.integers(0, LANE))
+    a = CompressedTensor(signs=jnp.asarray(rng.integers(-1, 2, (n,)),
+                                           jnp.int8),
+                         scale=jnp.float32(rng.normal()))
+    b = CompressedTensor(signs=jnp.asarray(rng.integers(-1, 2, (n,)),
+                                           jnp.int8),
+                         scale=jnp.float32(rng.normal()))
+    got = float(ops.expert_dot(pack_ternary(a), pack_ternary(b)))
+    want = float(np.dot(np.asarray(a.signs, np.int64),
+                        np.asarray(b.signs, np.int64))) \
+        * float(a.scale) * float(b.scale)
+    assert got == pytest.approx(want, rel=1e-6, abs=1e-6)
 
 
 if HAVE_HYPOTHESIS:
     @settings(max_examples=20, deadline=None)
     @given(st.integers(1, 8))
-    def test_popcount_dot_property(seed):
-        _popcount_dot_property(seed)
+    def test_expert_dot_property(seed):
+        _expert_dot_property(seed)
 else:
     @pytest.mark.parametrize("seed", range(1, 9))
-    def test_popcount_dot_property(seed):
-        _popcount_dot_property(seed)
+    def test_expert_dot_property(seed):
+        _expert_dot_property(seed)
 
 
 # ---------------------------------------------------------------------------
@@ -318,3 +321,56 @@ def test_ops_expert_dot_matches_core():
     got = float(ops.expert_dot(pa, pb))
     want = float(scaled_dot(pa, pb))
     assert got == pytest.approx(want)
+
+
+# ---------------------------------------------------------------------------
+# TPU-legal tiling: several blocks per grid dim, overhanging edge blocks
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("M,K,N,transpose,bm,bn,bk", [
+    (5, 512, 6400, False, 128, 4096, 256),   # 200 words: 128 + edge of 72
+    (9, 256, 300, True, 8, 128, 512),        # 300 rows: 128, 128, edge 44
+    (260, 256, 96, False, 128, 4096, 128),   # rows pad to 384, 2 k-steps
+])
+def test_grouped_matmul_edge_blocks_match_ref(M, K, N, transpose, bm, bn,
+                                              bk):
+    E = 3
+    rng = np.random.default_rng(60)
+    if transpose:
+        pos, neg = rand_plane_stack(61, E, N, K)
+    else:
+        pos, neg = rand_plane_stack(61, E, K, N)
+    x = jnp.asarray(rng.normal(0, 1, (M, K)), jnp.float32)
+    scales = jnp.asarray(rng.normal(0, 0.5, E), jnp.float32)
+    eid = jnp.asarray(rng.integers(-1, E, M), jnp.int32)
+    got = ternary_matmul_grouped(x, pos, neg, scales, eid,
+                                 transpose_rhs=transpose, bm=bm, bn=bn,
+                                 bk=bk, interpret=True)
+    want = ref.ternary_matmul_grouped_ref(x, pos, neg, scales, eid,
+                                          transpose_rhs=transpose)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+    assert np.all(np.asarray(got)[np.asarray(eid) < 0] == 0.0)
+
+
+@pytest.mark.parametrize("M,N,bm,bn", [(300, 6400, 128, 4096),
+                                       (20, 4096 * 2, 8, 4096)])
+def test_pack_unpack_edge_blocks_bit_exact(M, N, bm, bn):
+    """Row and word edge blocks overhang the array: results stay
+    bit-identical to the oracles (nothing is padded or dropped)."""
+    rng = np.random.default_rng(62)
+    tau = jnp.asarray(rng.normal(0, 1, (M, N)), jnp.float32)
+    gp, gn = pack_ternary_planes(tau, jnp.float32(0.8), bm=bm, bn=bn,
+                                 interpret=True)
+    wp, wn = ref.pack_ternary_planes_ref(tau, jnp.float32(0.8))
+    np.testing.assert_array_equal(np.asarray(gp), np.asarray(wp))
+    np.testing.assert_array_equal(np.asarray(gn), np.asarray(wn))
+    base = jnp.asarray(rng.normal(0, 1, (M, N)), jnp.bfloat16)
+    pos, neg = jnp.stack([gp, wn]), jnp.stack([gn, wp & ~wn])
+    scales = jnp.asarray([0.3, -0.2], jnp.float32)
+    got = unpack_add_many(base, pos, neg, scales, bm=bm, bn=bn,
+                          interpret=True)
+    want = ref.unpack_add_many_ref(base, pos, neg, scales)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))

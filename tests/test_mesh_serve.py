@@ -120,6 +120,22 @@ print("ALL_OK")
     assert "ALL_OK" in out
 
 
+def test_cross_mesh_parity_packed_overlay():
+    """The packed overlay branches a TPU takes (grouped contraction,
+    packed embedding rows, packed tied head) run once per expert shard on
+    a mesh and stay bitwise equal to the one-device engine."""
+    out = run_sub(HEADER + """
+import functools
+from repro.models import delta as delta_mod
+from repro.serve import engine as engine_mod
+engine_mod.build_overlay = functools.partial(delta_mod.build_overlay,
+                                             materialize=False)
+check("dense", {})
+print("ALL_OK")
+""")
+    assert "ALL_OK" in out
+
+
 def test_mesh_device_cache_shards():
     """DeviceCache on a mesh: stacks pad E to the shard count with inert
     zero slots, per-shard budget accounting, and shard gauges."""

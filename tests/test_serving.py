@@ -525,3 +525,46 @@ def test_export_import_expert_roundtrip(tmp_path):
     anyleaf = next(iter(taus.values()))
     vals = np.unique(anyleaf)
     assert len(vals) <= 3
+
+
+@pytest.mark.parametrize("kv_layout", ["dense", "paged"])
+def test_packed_overlay_matches_materialized(monkeypatch, kv_layout):
+    """The overlay branches a TPU takes — packed planes through
+    ``grouped_delta_matmul``, the packed embedding-row gather and the
+    packed tied LM head — serve the same tokens as the materialized sign
+    stacks the CPU path builds by default."""
+    import functools
+
+    from repro.models import delta as delta_mod
+    from repro.serve import engine as engine_mod
+
+    cfg = get_smoke_config("qwen2_5_3b", n_units=2)
+    assert cfg.tie_embeddings
+    api = build(cfg)
+    base = api.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(4)
+    prompts = [jnp.asarray(rng.integers(1, cfg.vocab, 9), jnp.int32)
+               for _ in range(5)]
+
+    def serve(materialize):
+        monkeypatch.setattr(engine_mod, "build_overlay", functools.partial(
+            delta_mod.build_overlay, materialize=materialize))
+        eng = rapi.serve(api, RT, base, make_experts(api, base, n=3,
+                                                     scale=0.03),
+                         max_batch=4, cache_len=32, kv_layout=kv_layout)
+        reqs = [Request(uid=i, expert=f"expert{i % 3}", prompt=prompts[i],
+                        max_new_tokens=5) for i in range(5)]
+        eng.run(reqs)
+        overlays = list(eng._overlays.values())
+        assert overlays and all(
+            (leaf.dense is None) == (not materialize)
+            for ov in overlays
+            for leaf in jax.tree_util.tree_leaves(
+                ov, is_leaf=lambda x: isinstance(
+                    x, (delta_mod.MatmulDelta, delta_mod.EmbedDelta)))
+            if isinstance(leaf, (delta_mod.MatmulDelta,
+                                 delta_mod.EmbedDelta)))
+        assert eng.swap_summary()["n_swaps"] == 0
+        return {r.uid: list(r.out_tokens) for r in reqs}
+
+    assert serve(materialize=False) == serve(materialize=True)
