@@ -53,6 +53,7 @@ from repro.serve import decode_loop, paged_kv
 from repro.serve import journal as journal_mod
 from repro.serve import scheduler as scheduler_mod
 from repro.serve import snapshot as snapshot_mod
+from repro.serve import trace
 from repro.serve.decode_loop import SamplingConfig
 from repro.serve.expert_cache import (BASE, DeviceCache, ExpertRegistry,
                                       ExpertStore, ExpertUnavailable,
@@ -238,7 +239,13 @@ class ServeEngine:
         self._log_dropped = {"swap": 0, "wave": 0, "failed": 0}
         self._sched = None                  # last run's scheduler instance
         self._t0 = time.monotonic()         # run() resets; engine clock zero
-        self._adm_wait: dict[int, list] = defaultdict(list)
+        # per priority: [admitted, summed wait, longest wait] (bounded)
+        self._adm_wait: dict[int, list] = {}
+        # host spans and counters (repro.serve.trace); the prefill clock
+        # lets admission host time leave out the prefills it launches
+        self.counters = trace.new_counters()
+        self._host_s = {"prefill": 0.0}
+        self._wave_idx = 0                  # waves started, for span attrs
         self._kv_peak = 0                   # peak pool blocks in use
         self._kv_in_use = 0
         # --- crash consistency (repro.serve.journal / .snapshot) ---
@@ -262,12 +269,11 @@ class ServeEngine:
             return self.base
         if self._merged_name == expert:
             return self._merged_params
-        t0 = time.monotonic()
-        params = self.registry.merged_params(self.base, [expert])
+        with trace.span("engine.merge", expert=expert):
+            params = self.registry.merged_params(self.base, [expert])
         self._merged_name = expert
         self._merged_params = params
-        self._ring_append("swap", {"expert": expert,
-                                   "seconds": time.monotonic() - t0})
+        self._ring_append("swap", {"expert": expert})
         return params
 
     def merged_ensemble_params(self, experts: list[str],
@@ -282,23 +288,25 @@ class ServeEngine:
         """Zero-merge overlay for an ordered expert set (None → fallback)."""
         if self._plan is None:
             return None
-        if experts in self._overlays:
-            # an eviction of any member drops the underlying stack; the
-            # shaped overlay must not outlive it (HBM accounting + staleness)
-            if self.cache.has_stack(experts):
+        # an eviction of any member drops the underlying stack; the shaped
+        # overlay must not outlive it (HBM accounting + staleness)
+        hit = experts in self._overlays and self.cache.has_stack(experts)
+        with trace.span("engine.overlay", experts=len(experts),
+                        hit=int(hit)):
+            if hit:
                 # overlay reuse rides the resident stack — count it as a
                 # stack hit so stack_hit_rate reflects plane reuse even
                 # when the shaped overlay short-circuits cache.stacked()
                 self.cache.stats.stack_hits += 1
                 return self._overlays[experts]
-            del self._overlays[experts]
-        stacks = self.cache.stacked(experts)
-        overlay = build_overlay(self._plan, stacks, mesh=self.mesh)
-        if overlay is not None:
-            while len(self._overlays) >= DeviceCache.MAX_STACKS:
-                self._overlays.pop(next(iter(self._overlays)))
-            self._overlays[experts] = overlay
-        return overlay
+            self._overlays.pop(experts, None)
+            stacks = self.cache.stacked(experts)
+            overlay = build_overlay(self._plan, stacks, mesh=self.mesh)
+            if overlay is not None:
+                while len(self._overlays) >= DeviceCache.MAX_STACKS:
+                    self._overlays.pop(next(iter(self._overlays)))
+                self._overlays[experts] = overlay
+            return overlay
 
     # ---------------- graceful degradation ----------------
 
@@ -394,10 +402,11 @@ class ServeEngine:
         mode = scheduling or self.cfg.scheduling
         self._open_journal(requests, mode)
         try:
-            if mode == "grouped":
-                self._run_grouped(requests)
-            else:
-                self._run_mixed(requests)
+            with trace.gc_meter(self.counters):
+                if mode == "grouped":
+                    self._run_grouped(requests)
+                else:
+                    self._run_mixed(requests)
             for r in requests:
                 if r.status == PENDING:
                     r.status = DONE
@@ -526,10 +535,14 @@ class ServeEngine:
                 except ExpertUnavailable:
                     pass
         continued = demoted = 0
-        if snap is not None and any(by_uid[u].status == PENDING
-                                    for u in snap_uids):
-            continued, demoted = self._resume_wave(snap, by_uid, sched)
-        self._drain(sched)
+        with trace.gc_meter(self.counters):
+            if snap is not None and any(by_uid[u].status == PENDING
+                                        for u in snap_uids):
+                with self._wave_span(len(snap.row_uids),
+                                     len(snap.meta["experts"])):
+                    continued, demoted = self._resume_wave(snap, by_uid,
+                                                           sched)
+            self._drain(sched)
         for r in requests:
             if r.status == PENDING:
                 r.status = DONE
@@ -552,7 +565,6 @@ class ServeEngine:
         the shared chunk loop.  Returns ``(continued, demoted)`` row
         counts; on a failed expert refetch the dead expert's rows FAIL
         and every other incomplete row is demoted to a full re-serve."""
-        t0 = time.monotonic()
         experts = list(snap.meta["experts"])
         live = [u for u in snap.row_uids
                 if by_uid[u].status == PENDING]
@@ -606,8 +618,7 @@ class ServeEngine:
         self._ring_append("wave", {"rows": len(rows),
                                    "experts": len(experts),
                                    "admitted": admitted, "chunks": chunks,
-                                   "resumed": True,
-                                   "seconds": time.monotonic() - t0})
+                                   "resumed": True})
         return len(live), 0
 
     @staticmethod
@@ -638,7 +649,11 @@ class ServeEngine:
         for r in reqs:
             if r.t_admit_s is None:
                 r.t_admit_s = now
-                self._adm_wait[r.priority].append(now - r.arrival_s)
+                wait = now - r.arrival_s
+                w = self._adm_wait.setdefault(r.priority, [0, 0.0, wait])
+                w[0] += 1
+                w[1] += wait
+                w[2] = max(w[2], wait)
 
     def _mark_first(self, reqs: list[Request]) -> None:
         now = self._now()
@@ -740,12 +755,15 @@ class ServeEngine:
                 # so a clock hiccup never wedges the loop)
                 time.sleep(min(max(nxt - self._now(), 0.0), 0.05))
                 continue
-            wave, experts = sched.take_wave(self.cfg.max_batch,
-                                            self.cfg.max_stack)
+            with trace.span("engine.schedule", wave=self._wave_idx + 1,
+                            ready=sched.ready_count()):
+                wave, experts = sched.take_wave(self.cfg.max_batch,
+                                                self.cfg.max_stack)
+                if wave:
+                    self._prefetch_upcoming(
+                        sched.peek(4 * self.cfg.max_batch), extra=experts)
             if not wave:
                 continue
-            self._prefetch_upcoming(sched.peek(4 * self.cfg.max_batch),
-                                    extra=experts)
             overlay = None
             while wave:
                 try:
@@ -811,11 +829,39 @@ class ServeEngine:
 
     def _serve_wave(self, wave: list[Request], experts: list[str],
                     overlay: dict, sched) -> None:
-        if self.cfg.kv_layout == "paged":
-            return self._serve_wave_paged(wave, experts, overlay, sched)
-        if self.cfg.decode_chunk:
-            return self._serve_wave_chunked(wave, experts, overlay, sched)
-        return self._serve_wave_eager(wave, experts, overlay, sched)
+        with self._wave_span(len(wave), len(experts)):
+            if self.cfg.kv_layout == "paged":
+                return self._serve_wave_paged(wave, experts, overlay, sched)
+            if self.cfg.decode_chunk:
+                return self._serve_wave_chunked(wave, experts, overlay,
+                                                sched)
+            return self._serve_wave_eager(wave, experts, overlay, sched)
+
+    def _wave_span(self, rows: int, experts: int):
+        """``engine.wave`` around one wave, under the next wave index
+        (the ``wave`` attribute of every span inside it)."""
+        self._wave_idx += 1
+        return trace.span("engine.wave", wave=self._wave_idx, rows=rows,
+                          experts=experts)
+
+    def _prefill_span(self, reqs: list[Request], bucket: int):
+        """``engine.prefill`` around one prefill call: counted in
+        ``prefill_calls`` and timed on the prefill clock, which admission
+        host time leaves out."""
+        self.counters["prefill_calls"] += 1
+        attrs = {"wave": self._wave_idx, "rows": len(reqs),
+                 "bucket": bucket,
+                 "prompt_tokens": sum(int(r.prompt.shape[0]) for r in reqs)}
+        if len(reqs) == 1:
+            attrs["uid"] = reqs[0].uid
+        return trace.timed(self._host_s, "prefill", "engine.prefill",
+                           **attrs)
+
+    def _pack_span(self):
+        """``engine.prefill.pack``: host work before the prefill program
+        is enqueued, timed into ``prefill_pack_s``."""
+        return trace.timed(self.counters, "prefill_pack_s",
+                           "engine.prefill.pack")
 
     def _admission_block_reason(self, nxt: Request, cur: int, slot: dict,
                                 alloc) -> Optional[str]:
@@ -854,78 +900,90 @@ class ServeEngine:
         priority/affinity schedulers scan past a blocked candidate, so a
         head whose KV blocks are exhausted defers only itself instead of
         starving placeable requests behind it."""
-        sched.release(self._now())
-        refilled = []
-        if alloc is not None:
-            # reclaim every finished row's blocks up front so this round's
-            # candidates see the whole reclaimable pool
+        t0 = time.perf_counter()
+        prefill0 = self._host_s["prefill"]
+        ranked = 0
+        with trace.span("engine.admit", wave=self._wave_idx,
+                        slots=len(done)) as sp:
+            sched.release(self._now())
+            refilled = []
+            if alloc is not None:
+                # reclaim every finished row's blocks up front so this
+                # round's candidates see the whole reclaimable pool
+                for j in done:
+                    if j in row_blocks:
+                        alloc.free(row_blocks.pop(j))
+                self._kv_in_use = alloc.in_use
+            blocked = False               # strict-FIFO head-of-line block
             for j in done:
-                if j in row_blocks:
-                    alloc.free(row_blocks.pop(j))
-            self._kv_in_use = alloc.in_use
-        blocked = False               # strict-FIFO head-of-line block
-        for j in done:
-            if blocked:
-                break
-            admitted = rescan = True
-            while rescan and not blocked:
-                admitted = False
-                rescan = False
-                for nxt in sched.candidates(slot):
-                    reason = self._admission_block_reason(nxt, cur, slot,
-                                                          alloc)
-                    if reason is not None:
-                        if sched.strict_fifo:
-                            blocked = True
-                            break
-                        sched.note_deferred(reason)
-                        continue      # try the next placeable candidate
-                    if nxt.expert not in slot:
-                        try:
-                            grown = self._overlay_for(
-                                tuple(experts + [nxt.expert]))
-                        except ExpertUnavailable as e:
-                            # fail ONLY this request and rescan — a dead
-                            # expert must not block the admission queue
-                            sched.remove(nxt)
-                            self._fail([nxt], e)
-                            rescan = True
-                            break
-                        if grown is None:
-                            if sched.strict_fifo:
-                                blocked = True    # newcomer not coverable
-                                break
-                            sched.note_deferred("overlay")
-                            continue
-                        experts.append(nxt.expert)
-                        slot[nxt.expert] = len(experts) - 1
-                        overlay = grown
-                    else:
-                        # the row is served entirely from the wave's
-                        # resident stacked planes — the affinity lever
-                        self.cache.stats.stack_hits += 1
-                    sched.remove(nxt)
-                    rows[j] = nxt
-                    eid = eid.at[j].set(slot[nxt.expert])
-                    key_j = decode_loop.row_keys(self.cfg.sampling.seed,
-                                                 [nxt.uid])
-                    keys = keys.at[j].set(key_j[0])
-                    if alloc is not None:
-                        tok, cache = self._admit_row_paged(
-                            nxt, j, cache, tok, overlay, eid, key_j,
-                            alloc, row_blocks)
-                    else:
-                        tok, cache = self._admit_row(nxt, j, cur, cache,
-                                                     tok, overlay, eid,
-                                                     key_j)
-                    self._mark_admitted([nxt])
-                    self._mark_first([nxt])
-                    self._journal_admit(nxt, j)
-                    refilled.append(j)
-                    admitted = True
-                    break             # slot j filled; move to the next
-                if admitted:
+                if blocked:
                     break
+                admitted = rescan = True
+                while rescan and not blocked:
+                    admitted = False
+                    rescan = False
+                    cands = sched.candidates(slot)
+                    ranked += len(cands)
+                    for nxt in cands:
+                        reason = self._admission_block_reason(nxt, cur, slot,
+                                                              alloc)
+                        if reason is not None:
+                            if sched.strict_fifo:
+                                blocked = True
+                                break
+                            sched.note_deferred(reason)
+                            continue      # try the next placeable candidate
+                        if nxt.expert not in slot:
+                            try:
+                                grown = self._overlay_for(
+                                    tuple(experts + [nxt.expert]))
+                            except ExpertUnavailable as e:
+                                # fail ONLY this request and rescan — a dead
+                                # expert must not block the admission queue
+                                sched.remove(nxt)
+                                self._fail([nxt], e)
+                                rescan = True
+                                break
+                            if grown is None:
+                                if sched.strict_fifo:
+                                    blocked = True    # newcomer not coverable
+                                    break
+                                sched.note_deferred("overlay")
+                                continue
+                            experts.append(nxt.expert)
+                            slot[nxt.expert] = len(experts) - 1
+                            overlay = grown
+                        else:
+                            # the row is served entirely from the wave's
+                            # resident stacked planes — the affinity lever
+                            self.cache.stats.stack_hits += 1
+                        sched.remove(nxt)
+                        rows[j] = nxt
+                        eid = eid.at[j].set(slot[nxt.expert])
+                        key_j = decode_loop.row_keys(self.cfg.sampling.seed,
+                                                     [nxt.uid])
+                        keys = keys.at[j].set(key_j[0])
+                        if alloc is not None:
+                            tok, cache = self._admit_row_paged(
+                                nxt, j, cache, tok, overlay, eid, key_j,
+                                alloc, row_blocks)
+                        else:
+                            tok, cache = self._admit_row(nxt, j, cur, cache,
+                                                         tok, overlay, eid,
+                                                         key_j)
+                        self._mark_admitted([nxt])
+                        self._mark_first([nxt])
+                        self._journal_admit(nxt, j)
+                        refilled.append(j)
+                        admitted = True
+                        break             # slot j filled; move to the next
+                    if admitted:
+                        break
+            sp.set_metadata(admitted=len(refilled),
+                            candidates_ranked=ranked)
+        self.counters["admissions"] += len(refilled)
+        self.counters["admit_host_s"] += (time.perf_counter() - t0 - (
+            self._host_s["prefill"] - prefill0))
         return rows, experts, overlay, eid, tok, keys, cache, refilled
 
     def _serve_wave_eager(self, wave: list[Request], experts: list[str],
@@ -936,21 +994,10 @@ class ServeEngine:
         through the same on-device selector as the compiled loop, so
         temperature/top-k sampling is eager-vs-chunked reproducible: row
         streams depend only on (seed, uid, draw index)."""
-        t0 = time.monotonic()
         self._mark_admitted(wave)
         slot = {e: i for i, e in enumerate(experts)}
         eid = jnp.asarray([slot[r.expert] for r in wave], jnp.int32)
-        toks, start = self._pad_prompts(wave)
-        cur = int(toks.shape[1])           # host mirror of cache["cur"]
-        logits, cache = self._prefill(self.base, {"tokens": toks}, self.rt,
-                                      self.cfg.cache_len, delta=overlay,
-                                      eid=eid, start=start,
-                                      kv_sharding=self._kv_sharding_for(
-                                          len(wave)))
-        keys = decode_loop.row_keys(self.cfg.sampling.seed,
-                                    [r.uid for r in wave])
-        tok = self._select(logits, keys, jnp.zeros((len(wave),), jnp.int32))
-        self._mark_first(wave)
+        cache, tok, keys, cur = self._wave_prefill(wave, eid, overlay)
         rows: list[Optional[Request]] = list(wave)
         admitted = 0
         while True:
@@ -991,8 +1038,7 @@ class ServeEngine:
             cur += 1
         self._ring_append("wave", {"rows": len(wave),
                                    "experts": len(experts),
-                                   "admitted": admitted, "chunks": 0,
-                                   "seconds": time.monotonic() - t0})
+                                   "admitted": admitted, "chunks": 0})
 
     def _drive_chunk(self, params, overlay, eid, tok, cache, rows, keys):
         """Launch ONE compiled K-step chunk and flush its ``[B, K]`` token
@@ -1011,34 +1057,45 @@ class ServeEngine:
                for r in rows]
         if max(rem) == 0:
             return tok, cache, 0, False
-        # gen = tokens each row has generated so far (the pending ``tok``
-        # counts); indexes fold_in for reproducible sampling
-        gen = jnp.asarray([len(r.out_tokens) + 1 for r in rows], jnp.int32)
-        tok, cache, buf = self._chunk_fn(params, overlay, eid, tok, cache,
-                                         jnp.asarray(rem, jnp.int32), gen,
-                                         keys)
-        buf_np = np.asarray(buf)           # ONE host sync per K steps
-        flushed = []
-        for j, r in enumerate(rows):
-            n = min(K, rem[j])
-            if n:
-                toks = [int(t) for t in buf_np[j, :n]]
-                r.out_tokens.extend(toks)
-                self._mark_done(r)
-                flushed.append({"uid": r.uid, "n": n, "toks": toks,
-                                "total": len(r.out_tokens)})
-        self._chunk_idx += 1
-        # the chunk boundary IS the WAL sync point: tokens reach the OS
-        # before the next launch, so a SIGKILL costs at most one chunk
-        self._journal_append("chunk", {"i": self._chunk_idx,
-                                       "rows": flushed}, flush=True)
+        steps = decode_loop.host_decode_steps(max(rem), K)
+        with trace.span("engine.decode_chunk", wave=self._wave_idx,
+                        chunk=self._chunk_idx + 1, rows=len(rows)) as sp:
+            with trace.span("engine.decode_chunk.launch"):
+                # gen = tokens each row has generated so far (the pending
+                # ``tok`` counts); indexes fold_in for reproducible sampling
+                gen = jnp.asarray([len(r.out_tokens) + 1 for r in rows],
+                                  jnp.int32)
+                tok, cache, buf = self._chunk_fn(
+                    params, overlay, eid, tok, cache,
+                    jnp.asarray(rem, jnp.int32), gen, keys)
+            with trace.span("engine.decode_chunk.sync"):
+                buf_np = np.asarray(buf)   # ONE host sync per K steps
+            with trace.span("engine.decode_chunk.flush"):
+                flushed = []
+                for j, r in enumerate(rows):
+                    n = min(K, rem[j])
+                    if n:
+                        toks = [int(t) for t in buf_np[j, :n]]
+                        r.out_tokens.extend(toks)
+                        self._mark_done(r)
+                        flushed.append({"uid": r.uid, "n": n, "toks": toks,
+                                        "total": len(r.out_tokens)})
+                self._chunk_idx += 1
+                # the chunk boundary IS the WAL sync point: tokens reach
+                # the OS before the next launch, so a SIGKILL costs at
+                # most one chunk
+                self._journal_append("chunk", {"i": self._chunk_idx,
+                                               "rows": flushed}, flush=True)
+            sp.set_metadata(steps=steps,
+                            tokens=sum(min(K, n) for n in rem))
         if (self._recovery_t0 is not None
                 and "first_resumed_token_s" not in self.recovery_stats):
             self.recovery_stats["first_resumed_token_s"] = (
                 time.monotonic() - self._recovery_t0)
+        # outside the span: a hook's time is never counted as the engine's
         for hook in list(self.chunk_hooks):
             hook(self._chunk_idx)
-        return tok, cache, decode_loop.host_decode_steps(max(rem), K), True
+        return tok, cache, steps, True
 
     @staticmethod
     def _done_rows(rows) -> list:
@@ -1097,23 +1154,11 @@ class ServeEngine:
         token selection, KV writes) per compiled launch, ONE host sync per
         chunk to flush the ``[B, K]`` token buffer, then host-side
         admission via the shared :meth:`_chunk_loop` driver."""
-        t0 = time.monotonic()
         self._mark_admitted(wave)
         slot = {e: i for i, e in enumerate(experts)}
         eid = jnp.asarray([slot[r.expert] for r in wave], jnp.int32)
-        toks, start = self._pad_prompts(wave)
-        cur = int(toks.shape[1])           # host mirror of cache["cur"]
-        logits, cache = self._prefill(self.base, {"tokens": toks}, self.rt,
-                                      self.cfg.cache_len, delta=overlay,
-                                      eid=eid, start=start,
-                                      kv_sharding=self._kv_sharding_for(
-                                          len(wave)))
+        cache, tok, keys, cur = self._wave_prefill(wave, eid, overlay)
         rows: list[Request] = list(wave)
-        keys = decode_loop.row_keys(self.cfg.sampling.seed,
-                                    [r.uid for r in rows])
-        tok = self._select(logits, keys,
-                           jnp.zeros((len(rows),), jnp.int32))
-        self._mark_first(rows)
         for j, r in enumerate(rows):
             self._journal_admit(r, j)
         admitted, chunks = self._chunk_loop(rows, experts, slot, overlay,
@@ -1121,8 +1166,28 @@ class ServeEngine:
                                             cur=cur)
         self._ring_append("wave", {"rows": len(wave),
                                    "experts": len(experts),
-                                   "admitted": admitted, "chunks": chunks,
-                                   "seconds": time.monotonic() - t0})
+                                   "admitted": admitted, "chunks": chunks})
+
+    def _wave_prefill(self, wave: list[Request], eid, overlay) -> tuple:
+        """Dense wave prefill: left-pad the prompts to one width, prefill
+        the whole wave and select each row's first token.  Returns
+        ``(cache, tok, keys, cur)``, ``cur`` the host mirror of
+        ``cache["cur"]``."""
+        width = max(int(r.prompt.shape[0]) for r in wave)
+        with self._prefill_span(wave, width):
+            with self._pack_span():
+                toks, start = self._pad_prompts(wave)
+            with trace.span("engine.prefill.launch"):
+                logits, cache = self._prefill(
+                    self.base, {"tokens": toks}, self.rt, self.cfg.cache_len,
+                    delta=overlay, eid=eid, start=start,
+                    kv_sharding=self._kv_sharding_for(len(wave)))
+                keys = decode_loop.row_keys(self.cfg.sampling.seed,
+                                            [r.uid for r in wave])
+                tok = self._select(logits, keys,
+                                   jnp.zeros((len(wave),), jnp.int32))
+        self._mark_first(wave)
+        return cache, tok, keys, width
 
     def _admit_row(self, r: Request, j: int, cur: int, cache, tok,
                    overlay, eid, key_row):
@@ -1132,25 +1197,28 @@ class ServeEngine:
         attention ignores the left-pad positions — an admitted request
         matches the same prompt served solo."""
         row_start = cur - int(r.prompt.shape[0])
-        prompt = jnp.pad(r.prompt, (row_start, 0),
-                         constant_values=1)[None].astype(jnp.int32)
-        row_eid = eid[j][None]
-        row_logits, row_cache = self._prefill(
-            self.base, {"tokens": prompt}, self.rt, self.cfg.cache_len,
-            delta=overlay, eid=row_eid,
-            start=jnp.asarray([row_start], jnp.int32))
 
         def splice(c, rc):
             if c.ndim >= 2 and rc.ndim == c.ndim and rc.shape[1] == 1:
                 return c.at[:, j].set(rc[:, 0])
             return c
-        new_cache = dict(cache)
-        new_cache["layers"] = jax.tree_util.tree_map(splice, cache["layers"],
-                                                     row_cache["layers"])
-        new_cache["start"] = cache["start"].at[j].set(row_start)
-        first = self._select(row_logits, key_row,
-                             jnp.zeros((1,), jnp.int32))   # [1, 1]
-        tok = tok.at[j].set(first[0])
+        with self._prefill_span([r], cur):
+            with self._pack_span():
+                prompt = jnp.pad(r.prompt, (row_start, 0),
+                                 constant_values=1)[None].astype(jnp.int32)
+                row_eid = eid[j][None]
+            with trace.span("engine.prefill.launch"):
+                row_logits, row_cache = self._prefill(
+                    self.base, {"tokens": prompt}, self.rt,
+                    self.cfg.cache_len, delta=overlay, eid=row_eid,
+                    start=jnp.asarray([row_start], jnp.int32))
+                new_cache = dict(cache)
+                new_cache["layers"] = jax.tree_util.tree_map(
+                    splice, cache["layers"], row_cache["layers"])
+                new_cache["start"] = cache["start"].at[j].set(row_start)
+                first = self._select(row_logits, key_row,
+                                     jnp.zeros((1,), jnp.int32))   # [1, 1]
+                tok = tok.at[j].set(first[0])
         return tok, new_cache
 
     # ---------------- paged-KV wave driver ----------------
@@ -1163,27 +1231,34 @@ class ServeEngine:
         slot order is position order and the per-row caches drop straight
         into ``lp // block_size`` pool blocks.  No batch re-padding, no
         per-row splice into a running cache."""
-        jsa = jnp.asarray(js, jnp.int32)
-        toks = jnp.stack([jnp.pad(r.prompt, (lp - r.prompt.shape[0], 0),
-                                  constant_values=1) for r in reqs]
-                         ).astype(jnp.int32)
-        start = jnp.asarray([lp - int(r.prompt.shape[0]) for r in reqs],
-                            jnp.int32)
-        logits, row_cache = self._prefill(self.base, {"tokens": toks},
-                                          self.rt, lp, delta=overlay,
-                                          eid=eid[jsa], start=start)
-        row_layers = {name: {"k": st["k"], "v": st["v"]}
-                      for name, st in row_cache["layers"].items()}
-        N, nbp = len(js), lp // self._bs
-        ptab = np.asarray([row_blocks[j][:nbp] for j in js], np.int32)
-        tables = np.full((N, self._max_blocks), -1, np.int32)
-        for i, j in enumerate(js):
-            tables[i, :len(row_blocks[j])] = row_blocks[j]
-        cache = paged_kv.insert_prefill_rows(
-            cache, row_layers, jsa, jnp.asarray(ptab), jnp.asarray(tables),
-            jnp.full((N,), lp, jnp.int32), start)
-        first = self._select(logits, keys_rows, jnp.zeros((N,), jnp.int32))
-        tok = tok.at[jsa].set(first)
+        with self._prefill_span(reqs, lp):
+            with self._pack_span():
+                jsa = jnp.asarray(js, jnp.int32)
+                toks = jnp.stack([jnp.pad(r.prompt,
+                                          (lp - r.prompt.shape[0], 0),
+                                          constant_values=1) for r in reqs]
+                                 ).astype(jnp.int32)
+                start = jnp.asarray([lp - int(r.prompt.shape[0])
+                                     for r in reqs], jnp.int32)
+                N, nbp = len(js), lp // self._bs
+                ptab = np.asarray([row_blocks[j][:nbp] for j in js],
+                                  np.int32)
+                tables = np.full((N, self._max_blocks), -1, np.int32)
+                for i, j in enumerate(js):
+                    tables[i, :len(row_blocks[j])] = row_blocks[j]
+            with trace.span("engine.prefill.launch"):
+                logits, row_cache = self._prefill(
+                    self.base, {"tokens": toks}, self.rt, lp, delta=overlay,
+                    eid=eid[jsa], start=start)
+                row_layers = {name: {"k": st["k"], "v": st["v"]}
+                              for name, st in row_cache["layers"].items()}
+                cache = paged_kv.insert_prefill_rows(
+                    cache, row_layers, jsa, jnp.asarray(ptab),
+                    jnp.asarray(tables), jnp.full((N,), lp, jnp.int32),
+                    start)
+                first = self._select(logits, keys_rows,
+                                     jnp.zeros((N,), jnp.int32))
+                tok = tok.at[jsa].set(first)
         return tok, cache
 
     def _admit_row_paged(self, r: Request, j: int, cache, tok, overlay,
@@ -1210,7 +1285,6 @@ class ServeEngine:
         a finished row's blocks return to the pool and any queued request
         whose block need fits is placeable — regardless of prompt length
         or how far the wave has decoded."""
-        t0 = time.monotonic()
         alloc = paged_kv.BlockAllocator(self._kv_blocks, self._bs)
         row_blocks: dict[int, list] = {}
         kept: list[Request] = []
@@ -1270,27 +1344,28 @@ class ServeEngine:
         self._ring_append("wave", {"rows": len(wave),
                                    "experts": len(experts),
                                    "admitted": admitted, "chunks": chunks,
-                                   "kv_blocks_peak": alloc.peak_in_use,
-                                   "seconds": time.monotonic() - t0})
+                                   "kv_blocks_peak": alloc.peak_in_use})
 
     def _serve_batch(self, params, reqs: list[Request]) -> None:
         """Merge-path batch (single expert): prefill then decode."""
         self._mark_admitted(reqs)
-        toks, start = self._pad_prompts(reqs)
-        batch = {"tokens": toks}
-        if self.api.cfg.frontend is not None:
-            n = self.api.cfg.frontend.n_tokens
-            e = self.api.cfg.frontend.embed_dim
-            stub = jnp.zeros((len(reqs), n, e), jnp.float32)
-            key = ("frames" if self.api.cfg.family == "audio"
-                   else "mm_embeds")
-            batch[key] = stub
-        logits, cache = self._prefill(params, batch, self.rt,
-                                      self.cfg.cache_len,
-                                      start=(start if self._row_mask_ok()
-                                             else None),
-                                      kv_sharding=self._kv_sharding_for(
-                                          len(reqs)))
+        with self._prefill_span(reqs, max(int(r.prompt.shape[0])
+                                          for r in reqs)):
+            with self._pack_span():
+                toks, start = self._pad_prompts(reqs)
+                batch = {"tokens": toks}
+                if self.api.cfg.frontend is not None:
+                    n = self.api.cfg.frontend.n_tokens
+                    e = self.api.cfg.frontend.embed_dim
+                    stub = jnp.zeros((len(reqs), n, e), jnp.float32)
+                    key = ("frames" if self.api.cfg.family == "audio"
+                           else "mm_embeds")
+                    batch[key] = stub
+            with trace.span("engine.prefill.launch"):
+                logits, cache = self._prefill(
+                    params, batch, self.rt, self.cfg.cache_len,
+                    start=start if self._row_mask_ok() else None,
+                    kv_sharding=self._kv_sharding_for(len(reqs)))
         if self.cfg.decode_chunk:
             return self._decode_batch_chunked(params, reqs, logits, cache)
         keys = decode_loop.row_keys(self.cfg.sampling.seed,
@@ -1329,8 +1404,8 @@ class ServeEngine:
             "policy": self.cfg.scheduler, "queue_depth_max": 0,
             "deferred": 0}
         s["admission_wait_s"] = {
-            str(p): {"n": len(w), "mean": sum(w) / len(w), "max": max(w)}
-            for p, w in sorted(self._adm_wait.items()) if w}
+            str(p): {"n": n, "mean": total / n, "max": longest}
+            for p, (n, total, longest) in sorted(self._adm_wait.items())}
         return s
 
     def _kv_stats(self) -> dict:
@@ -1345,7 +1420,6 @@ class ServeEngine:
     def swap_summary(self) -> dict:
         s = self.cache.stats.as_dict()
         s["n_swaps"] = len(self.swap_log)
-        s["swap_seconds"] = sum(x["seconds"] for x in self.swap_log)
         s["n_waves"] = len(self.wave_log)
         s["admitted"] = sum(x["admitted"] for x in self.wave_log)
         s["failed"] = self.failed_total
@@ -1355,6 +1429,7 @@ class ServeEngine:
         s["stack_hit_rate"] = hits / max(hits + builds, 1)
         s["scheduler"] = self._scheduler_stats()
         s["kv"] = self._kv_stats()
+        s["counters"] = dict(self.counters)
         if self.mesh is not None:
             s["mesh"] = dict(self.mesh.shape)
             s["shards"] = self.cache.shard_summary()

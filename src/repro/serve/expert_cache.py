@@ -55,6 +55,7 @@ from repro.expert import GOLOMB, PACKED, Expert, as_expert
 
 # canonical sign->planes bridge lives with the Expert artifact now
 from repro.expert import planes_from_signs as _planes_from_signs  # noqa: F401
+from repro.serve import trace
 from repro.transport.retry import ExpertNotFound
 from repro.transport.wire import TransportError, WireFormatError
 
@@ -623,10 +624,12 @@ class DeviceCache:
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             target = NamedSharding(self.mesh, PartitionSpec())
-        packed = jax.tree_util.tree_map(
-            lambda x: jax.device_put(x, target), host_packed,
-            is_leaf=lambda x: hasattr(x, "pos"))
-        size = tree_packed_bytes(packed)
+        with trace.span("engine.promote", expert=name) as sp:
+            packed = jax.tree_util.tree_map(
+                lambda x: jax.device_put(x, target), host_packed,
+                is_leaf=lambda x: hasattr(x, "pos"))
+            size = tree_packed_bytes(packed)
+            sp.set_metadata(bytes=size)
         while self._cache and (self.shard_resident_bytes() + size
                                > self.capacity):
             self._evict_one()
@@ -676,15 +679,18 @@ class DeviceCache:
         # only the BASE sentinel maps to a zero slot; unknown names must
         # fail loudly, exactly like the merge path's store.get
         trees = [{} if n == BASE else self.fetch(n) for n in key]
-        stacks = stack_packed(trees)
+        with trace.span("engine.stack_build", experts=len(key)) as sp:
+            stacks = stack_packed(trees)
+            if self.mesh is not None:
+                stacks = self._shard_stacks(stacks, len(key))
+            nbytes = stacked_bytes(stacks)
+            sp.set_metadata(bytes=nbytes)
         self._stack_real[tuple(key)] = len(key)
-        if self.mesh is not None:
-            stacks = self._shard_stacks(stacks, len(key))
         while len(self._stacks) >= self.MAX_STACKS:
             self._drop_stack(next(iter(self._stacks)))
         self._stacks[key] = stacks
         self.stats.stack_builds += 1
-        self.stats.stack_bytes += stacked_bytes(stacks)
+        self.stats.stack_bytes += nbytes
         self._enforce_budget(protect=key)
         return stacks
 
