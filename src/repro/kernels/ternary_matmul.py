@@ -36,6 +36,13 @@ The stored plane layout is the wire layout; nothing is rearranged per
 step.  Grid: (M/BM, N/BN, K/BK) with K innermost, accumulating in the VMEM
 output block; blocks follow the (8, 128) tiling rules of
 :mod:`repro.kernels.tpu_params`.
+
+Expert skip.  A row tile runs the unpack and dots of only the experts it
+has rows of (:func:`tile_presence`; their count and ids reach the kernel
+by scalar prefetch): a prefill tile lies inside one request, so it does
+one expert's work whatever E is, while a decode tile that mixes every
+expert runs the same E-expert loop as without the skip.  A skipped term
+was an exact zero, so every row's result is unchanged.
 """
 
 from __future__ import annotations
@@ -46,6 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tpu_params import (divisor_block, grouped_matmul_cost,
                                       lane_block, sublane_block,
@@ -76,8 +84,51 @@ def _row_scale(eid, scales_ref, n_e: int):
     return s
 
 
-def _kernel_cols(xt_ref, pos_ref, neg_ref, scales_ref, eid_ref, o_ref, *,
-                 n_k: int, n_e: int):
+def tile_presence(eid, bm: int, n_e: int, xp=jnp):
+    """``present[i, e]``: 1 when row tile ``i`` (rows ``i*bm`` up to
+    ``(i+1)*bm``) holds a row of expert ``e``, else 0; -1 rows and the
+    padding of a last partial tile hold none.  int32 [ceil(M/bm), n_e].
+
+    The grouped kernels run an expert's unpack and dots in a row tile only
+    where this reads 1 (the skipped terms are exact zeros).  ``xp`` is
+    ``jnp`` in the kernel wrappers and ``numpy`` where the serving engine
+    counts the skipped passes on the host."""
+    eid = xp.pad(eid, (0, (-eid.shape[0]) % bm), constant_values=-1)
+    hit = eid.reshape(-1, bm, 1) == xp.arange(n_e, dtype=eid.dtype)
+    return hit.any(axis=1).astype(xp.int32)
+
+
+def row_block(m: int, bm: int = 128) -> int:
+    """The grouped kernel's row block for ``m`` rows under the bound
+    ``bm``: the same in both plane forms for the default bound (whole
+    when ``m <= bm``, else ``bm``)."""
+    return lane_block(bm, m)
+
+
+def tile_schedule(eid, bm: int, n_e: int) -> jax.Array:
+    """Per row tile, the count of its present experts and their ids in
+    ascending order (padding after them): int32 [tiles, 1 + n_e],
+    flattened for scalar prefetch."""
+    present = tile_presence(eid, bm, n_e)
+    ids = jnp.argsort(1 - present, axis=1, stable=True)
+    return jnp.concatenate([present.sum(axis=1, keepdims=True), ids],
+                           axis=1).astype(jnp.int32).reshape(-1)
+
+
+def _for_present_experts(sched_ref, n_e: int, body) -> None:
+    """``body(es)`` once per grid step, ``es`` the experts with a row in
+    the step's row tile (:func:`tile_schedule`, in SMEM): one unrolled
+    specialisation per count, so a tile of all E runs the E-expert loop
+    with static ids and a tile of one expert runs a one-expert loop."""
+    row = pl.program_id(0) * (n_e + 1)
+    for c in range(1, n_e + 1):
+        es = (list(range(n_e)) if c == n_e else
+              [sched_ref[row + 1 + s] for s in range(c)])
+        pl.when(sched_ref[row] == c)(functools.partial(body, es))
+
+
+def _kernel_cols(sched_ref, xt_ref, pos_ref, neg_ref, scales_ref, eid_ref,
+                 o_ref, *, n_k: int, n_e: int):
     """Planes packed along N, word-major.  xt [BK, BM] (x transposed);
     planes [E, BW, BK]; eid [1, BM]; o [32, BW, BM] (bit-major y^T)."""
     k = pl.program_id(2)
@@ -86,28 +137,32 @@ def _kernel_cols(xt_ref, pos_ref, neg_ref, scales_ref, eid_ref, o_ref, *,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    xt = xt_ref[...].astype(jnp.float32)
     eid = eid_ref[...]
-    xs = [xt * (eid == e).astype(jnp.float32) for e in range(n_e)]
 
-    def bit(b, carry):
-        acc = jnp.zeros(o_ref.shape[1:], jnp.float32)
-        for e in range(n_e):              # static unroll over E
-            t = plane_signs(pos_ref[e], neg_ref[e], b)      # [BW, BK]
-            acc += jnp.dot(t, xs[e], precision=_HI,
-                           preferred_element_type=jnp.float32)
-        o_ref[b] += acc
-        return carry
+    def experts(es):
+        xt = xt_ref[...].astype(jnp.float32)
+        xs = [xt * (eid == e).astype(jnp.float32) for e in es]
 
-    lax.fori_loop(0, LANE, bit, 0)
+        def bit(b, carry):
+            acc = jnp.zeros(o_ref.shape[1:], jnp.float32)
+            for e, x in zip(es, xs):        # unrolled over the tile's experts
+                t = plane_signs(pos_ref[e], neg_ref[e], b)    # [BW, BK]
+                acc += jnp.dot(t, x, precision=_HI,
+                               preferred_element_type=jnp.float32)
+            o_ref[b] += acc
+            return carry
+
+        lax.fori_loop(0, LANE, bit, 0)
+
+    _for_present_experts(sched_ref, n_e, experts)
 
     @pl.when(k == n_k - 1)
     def _scale():
         o_ref[...] *= _row_scale(eid, scales_ref, n_e)[None]
 
 
-def _kernel_rows(x_ref, pos_ref, neg_ref, scales_ref, eid_ref, o_ref, *,
-                 n_k: int, n_e: int):
+def _kernel_rows(sched_ref, x_ref, pos_ref, neg_ref, scales_ref, eid_ref,
+                 o_ref, *, n_k: int, n_e: int):
     """Planes packed along K, word-major.  x [32, BM, BW] (bit-major);
     planes [E, BW, BN]; eid [BM, 1]; o [BM, BN]."""
     k = pl.program_id(2)
@@ -117,19 +172,23 @@ def _kernel_rows(x_ref, pos_ref, neg_ref, scales_ref, eid_ref, o_ref, *,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     eid = eid_ref[...]
-    sel = [(eid == e).astype(jnp.float32) for e in range(n_e)]
 
-    def bit(b, carry):
-        xb = x_ref[b].astype(jnp.float32)             # [BM, BW]
-        acc = jnp.zeros(o_ref.shape, jnp.float32)
-        for e in range(n_e):
-            t = plane_signs(pos_ref[e], neg_ref[e], b)   # [BW, BN]
-            acc += jnp.dot(xb * sel[e], t, precision=_HI,
-                           preferred_element_type=jnp.float32)
-        o_ref[...] += acc
-        return carry
+    def experts(es):
+        sel = [(eid == e).astype(jnp.float32) for e in es]
 
-    lax.fori_loop(0, LANE, bit, 0)
+        def bit(b, carry):
+            xb = x_ref[b].astype(jnp.float32)               # [BM, BW]
+            acc = jnp.zeros(o_ref.shape, jnp.float32)
+            for e, s in zip(es, sel):
+                t = plane_signs(pos_ref[e], neg_ref[e], b)  # [BW, BN]
+                acc += jnp.dot(xb * s, t, precision=_HI,
+                               preferred_element_type=jnp.float32)
+            o_ref[...] += acc
+            return carry
+
+        lax.fori_loop(0, LANE, bit, 0)
+
+    _for_present_experts(sched_ref, n_e, experts)
 
     @pl.when(k == n_k - 1)
     def _scale():
@@ -190,15 +249,18 @@ def _grouped_cols(x, pos, neg, scales2, eid, *, bm, bn, bk, interpret):
     n_k = K // bk
     out = pl.pallas_call(
         functools.partial(_kernel_cols, n_k=n_k, n_e=E),
-        grid=(Mp // bm, pl.cdiv(Wn, bw), n_k),
-        in_specs=[
-            pl.BlockSpec((bk, bm), lambda i, j, k: (k, i)),
-            pl.BlockSpec((E, bw, bk), lambda i, j, k: (0, j, k)),
-            pl.BlockSpec((E, bw, bk), lambda i, j, k: (0, j, k)),
-            pl.BlockSpec((E, 1), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((1, bm), lambda i, j, k: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((LANE, bw, bm), lambda i, j, k: (0, j, i)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Mp // bm, pl.cdiv(Wn, bw), n_k),
+            in_specs=[
+                pl.BlockSpec((bk, bm), lambda i, j, k, _: (k, i)),
+                pl.BlockSpec((E, bw, bk), lambda i, j, k, _: (0, j, k)),
+                pl.BlockSpec((E, bw, bk), lambda i, j, k, _: (0, j, k)),
+                pl.BlockSpec((E, 1), lambda i, j, k, _: (0, 0)),
+                pl.BlockSpec((1, bm), lambda i, j, k, _: (0, i)),
+            ],
+            out_specs=pl.BlockSpec((LANE, bw, bm),
+                                   lambda i, j, k, _: (0, j, i))),
         out_shape=jax.ShapeDtypeStruct((LANE, Wn, Mp), jnp.float32),
         # i/j tiles are independent; k accumulates into the output block
         compiler_params=tpu_compiler_params(
@@ -206,7 +268,8 @@ def _grouped_cols(x, pos, neg, scales2, eid, *, bm, bn, bk, interpret):
         cost_estimate=grouped_matmul_cost(Mp, Wn * LANE, K, E,
                                           elem_bytes=x.dtype.itemsize),
         interpret=interpret,
-    )(x.T, pos, neg, scales2, eid.reshape(1, -1))
+    )(tile_schedule(eid, bm, E), x.T, pos, neg, scales2,
+      eid.reshape(1, -1))
     # o[b, w, m] = y[m, 32w + b]
     return jnp.transpose(out, (2, 1, 0)).reshape(Mp, Wn * LANE)[:M]
 
@@ -229,22 +292,25 @@ def _grouped_rows(x, pos, neg, scales2, eid, *, bm, bn, bk, interpret):
     n_k = Wk // bw
     out = pl.pallas_call(
         functools.partial(_kernel_rows, n_k=n_k, n_e=E),
-        grid=(Mp // bm, pl.cdiv(N, bn), n_k),
-        in_specs=[
-            pl.BlockSpec((LANE, bm, bw), lambda i, j, k: (0, i, k)),
-            pl.BlockSpec((E, bw, bn), lambda i, j, k: (0, k, j)),
-            pl.BlockSpec((E, bw, bn), lambda i, j, k: (0, k, j)),
-            pl.BlockSpec((E, 1), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Mp // bm, pl.cdiv(N, bn), n_k),
+            in_specs=[
+                pl.BlockSpec((LANE, bm, bw), lambda i, j, k, _: (0, i, k)),
+                pl.BlockSpec((E, bw, bn), lambda i, j, k, _: (0, k, j)),
+                pl.BlockSpec((E, bw, bn), lambda i, j, k, _: (0, k, j)),
+                pl.BlockSpec((E, 1), lambda i, j, k, _: (0, 0)),
+                pl.BlockSpec((bm, 1), lambda i, j, k, _: (i, 0)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, _: (i, j))),
         out_shape=jax.ShapeDtypeStruct((Mp, N), jnp.float32),
         compiler_params=tpu_compiler_params(
             ("parallel", "parallel", "arbitrary"), interpret=interpret),
         cost_estimate=grouped_matmul_cost(Mp, N, Wk * LANE, E,
                                           elem_bytes=x.dtype.itemsize),
         interpret=interpret,
-    )(xb, pos, neg, scales2, eid.reshape(-1, 1))
+    )(tile_schedule(eid, bm, E), xb, pos, neg, scales2,
+      eid.reshape(-1, 1))
     return out[:M]
 
 

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed.fault import RecoveryPlan
+from repro.kernels.ternary_matmul import row_block, tile_presence
 from repro.models.delta import build_overlay, plan_overlay
 from repro.models.model import ModelApi
 from repro.models.transformer import Runtime
@@ -844,16 +845,33 @@ class ServeEngine:
         return trace.span("engine.wave", wave=self._wave_idx, rows=rows,
                           experts=experts)
 
-    def _prefill_span(self, reqs: list[Request], bucket: int):
+    def _prefill_span(self, reqs: list[Request], bucket: int,
+                      slot: Optional[dict] = None):
         """``engine.prefill`` around one prefill call: counted in
         ``prefill_calls`` and timed on the prefill clock, which admission
-        host time leaves out."""
+        host time leaves out.
+
+        A prefill through the wave's overlay (``slot``: expert -> stack
+        index) also counts the grouped kernel's expert passes over its
+        rows, each prompt padded to ``bucket``: row tiles × stacked
+        experts in ``prefill_expert_passes``, and in
+        ``prefill_expert_passes_skipped`` those whose expert has no row
+        in the tile (the kernel's own rule, from host-side ids)."""
         self.counters["prefill_calls"] += 1
         attrs = {"wave": self._wave_idx, "rows": len(reqs),
                  "bucket": bucket,
                  "prompt_tokens": sum(int(r.prompt.shape[0]) for r in reqs)}
         if len(reqs) == 1:
             attrs["uid"] = reqs[0].uid
+        if slot is not None:
+            attrs["stack_experts"] = len(slot)
+            eid_rows = np.repeat(np.asarray([slot[r.expert] for r in reqs],
+                                            np.int32), bucket)
+            present = tile_presence(eid_rows, row_block(eid_rows.size),
+                                    len(slot), xp=np)
+            self.counters["prefill_expert_passes"] += present.size
+            self.counters["prefill_expert_passes_skipped"] += int(
+                present.size - present.sum())
         return trace.timed(self._host_s, "prefill", "engine.prefill",
                            **attrs)
 
@@ -966,11 +984,11 @@ class ServeEngine:
                         if alloc is not None:
                             tok, cache = self._admit_row_paged(
                                 nxt, j, cache, tok, overlay, eid, key_j,
-                                alloc, row_blocks)
+                                alloc, row_blocks, slot)
                         else:
                             tok, cache = self._admit_row(nxt, j, cur, cache,
                                                          tok, overlay, eid,
-                                                         key_j)
+                                                         key_j, slot)
                         self._mark_admitted([nxt])
                         self._mark_first([nxt])
                         self._journal_admit(nxt, j)
@@ -997,7 +1015,8 @@ class ServeEngine:
         self._mark_admitted(wave)
         slot = {e: i for i, e in enumerate(experts)}
         eid = jnp.asarray([slot[r.expert] for r in wave], jnp.int32)
-        cache, tok, keys, cur = self._wave_prefill(wave, eid, overlay)
+        cache, tok, keys, cur = self._wave_prefill(wave, eid, overlay,
+                                                   slot)
         rows: list[Optional[Request]] = list(wave)
         admitted = 0
         while True:
@@ -1157,7 +1176,8 @@ class ServeEngine:
         self._mark_admitted(wave)
         slot = {e: i for i, e in enumerate(experts)}
         eid = jnp.asarray([slot[r.expert] for r in wave], jnp.int32)
-        cache, tok, keys, cur = self._wave_prefill(wave, eid, overlay)
+        cache, tok, keys, cur = self._wave_prefill(wave, eid, overlay,
+                                                   slot)
         rows: list[Request] = list(wave)
         for j, r in enumerate(rows):
             self._journal_admit(r, j)
@@ -1168,13 +1188,14 @@ class ServeEngine:
                                    "experts": len(experts),
                                    "admitted": admitted, "chunks": chunks})
 
-    def _wave_prefill(self, wave: list[Request], eid, overlay) -> tuple:
+    def _wave_prefill(self, wave: list[Request], eid, overlay,
+                      slot: dict) -> tuple:
         """Dense wave prefill: left-pad the prompts to one width, prefill
         the whole wave and select each row's first token.  Returns
         ``(cache, tok, keys, cur)``, ``cur`` the host mirror of
         ``cache["cur"]``."""
         width = max(int(r.prompt.shape[0]) for r in wave)
-        with self._prefill_span(wave, width):
+        with self._prefill_span(wave, width, slot):
             with self._pack_span():
                 toks, start = self._pad_prompts(wave)
             with trace.span("engine.prefill.launch"):
@@ -1190,7 +1211,7 @@ class ServeEngine:
         return cache, tok, keys, width
 
     def _admit_row(self, r: Request, j: int, cur: int, cache, tok,
-                   overlay, eid, key_row):
+                   overlay, eid, key_row, slot: dict):
         """Prefill one newcomer left-padded to the wave position and splice
         its KV state into row j of the running batch.  The row's ``start``
         (= cur - prompt length) rides along, so the spliced row's decode
@@ -1202,7 +1223,7 @@ class ServeEngine:
             if c.ndim >= 2 and rc.ndim == c.ndim and rc.shape[1] == 1:
                 return c.at[:, j].set(rc[:, 0])
             return c
-        with self._prefill_span([r], cur):
+        with self._prefill_span([r], cur, slot):
             with self._pack_span():
                 prompt = jnp.pad(r.prompt, (row_start, 0),
                                  constant_values=1)[None].astype(jnp.int32)
@@ -1224,14 +1245,15 @@ class ServeEngine:
     # ---------------- paged-KV wave driver ----------------
 
     def _paged_prefill(self, reqs: list[Request], js: list[int], lp: int,
-                       cache, tok, overlay, eid, keys_rows, row_blocks):
+                       cache, tok, overlay, eid, keys_rows, row_blocks,
+                       slot: dict):
         """Prefill N rows (all bucketed to prompt width ``lp``) and scatter
         their KV into the block pool.  The rows run a *dense* prefill at
         ``cache_len = lp`` — with T == S the ring fill is the identity, so
         slot order is position order and the per-row caches drop straight
         into ``lp // block_size`` pool blocks.  No batch re-padding, no
         per-row splice into a running cache."""
-        with self._prefill_span(reqs, lp):
+        with self._prefill_span(reqs, lp, slot):
             with self._pack_span():
                 jsa = jnp.asarray(js, jnp.int32)
                 toks = jnp.stack([jnp.pad(r.prompt,
@@ -1262,7 +1284,7 @@ class ServeEngine:
         return tok, cache
 
     def _admit_row_paged(self, r: Request, j: int, cache, tok, overlay,
-                         eid, key_row, alloc, row_blocks):
+                         eid, key_row, alloc, row_blocks, slot: dict):
         """Paged slot refill: allocate the row's blocks and write its
         prefill KV.  Unlike the dense path there is no wave position to
         left-pad against and no ring to wrap — any prompt length admits
@@ -1274,7 +1296,7 @@ class ServeEngine:
         self._kv_in_use = alloc.in_use
         self._kv_peak = max(self._kv_peak, alloc.peak_in_use)
         return self._paged_prefill([r], [j], lp, cache, tok, overlay, eid,
-                                   key_row, row_blocks)
+                                   key_row, row_blocks, slot)
 
     def _serve_wave_paged(self, wave: list[Request], experts: list[str],
                           overlay: dict, sched) -> None:
@@ -1321,7 +1343,7 @@ class ServeEngine:
             js = groups[lp]
             tok, cache = self._paged_prefill(
                 [rows[j] for j in js], js, lp, cache, tok, overlay, eid,
-                keys[jnp.asarray(js, jnp.int32)], row_blocks)
+                keys[jnp.asarray(js, jnp.int32)], row_blocks, slot)
         self._mark_first(rows)
         for j, r in enumerate(rows):
             self._journal_admit(r, j)

@@ -24,7 +24,8 @@ from jax.profiler import TraceAnnotation
 
 # every counter the engine keeps; all are totals since the engine was made
 COUNTERS = ("prefill_calls", "prefill_pack_s", "admissions", "admit_host_s",
-            "gc_collections", "gc_pause_s")
+            "gc_collections", "gc_pause_s", "prefill_expert_passes",
+            "prefill_expert_passes_skipped")
 
 
 class _Off:

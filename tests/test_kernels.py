@@ -18,7 +18,9 @@ from repro.core import CompressionConfig, compress, pack_ternary
 from repro.core.compeft import CompressedTensor
 from repro.kernels import ops, ref
 from repro.kernels.pack import pack_ternary_planes
-from repro.kernels.ternary_matmul import ternary_matmul, ternary_matmul_grouped
+from repro.kernels.ternary_matmul import (ternary_matmul,
+                                          ternary_matmul_grouped,
+                                          tile_presence)
 from repro.kernels.unpack_add import unpack_add, unpack_add_many
 
 LANE = 32
@@ -220,35 +222,137 @@ def test_ternary_matmul_small_bn_regression():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
-@pytest.mark.parametrize("M,K,N,E", [(8, 32, 32, 1), (13, 96, 64, 3),
-                                     (33, 64, 128, 4)])
-def test_grouped_matmul_bit_identical_to_single(M, K, N, E):
+def tiled_eid(M, E, bm, seed):
+    """Per-row expert ids whose row tiles of ``bm`` rows hold, in turn, one
+    expert's rows (the experts in turn, from the last), only -1 rows, and a
+    mix of -1 with experts 1..E-1 (0 alone when E == 1): the layouts the
+    kernel's per-tile expert skip sees, with present ids that differ from
+    the first ones of the stack."""
+    rng = np.random.default_rng(seed)
+    mixed = [-1, *range(min(1, E - 1), E)]
+    eid = np.empty(M, np.int32)
+    for t, lo in enumerate(range(0, M, bm)):
+        n = min(bm, M - lo)
+        kind = t % 3
+        eid[lo:lo + n] = (E - 1 - (t // 3) % E if kind == 0
+                          else -1 if kind == 1 else rng.choice(mixed, n))
+    return jnp.asarray(eid)
+
+
+def integer_x(M, K, seed):
+    """Integer-valued activations: every partial sum of x @ T is exact in
+    f32, so any summation order gives the same bits and the kernel can be
+    held bitwise to the oracle."""
+    return jnp.asarray(np.random.default_rng(seed).integers(-3, 4, (M, K)),
+                       jnp.float32)
+
+
+def single_expert_rows(x, pos, neg, scales, eid, transpose=False, **kw):
+    """Each row from a one-expert run of its own expert's planes (the
+    single-expert kernel, or the E=1 grouped kernel for ``transpose``),
+    zero for -1 rows."""
+    M = x.shape[0]
+    if transpose:
+        per = [ternary_matmul_grouped(x, pos[e:e + 1], neg[e:e + 1],
+                                      scales[e:e + 1],
+                                      jnp.zeros((M,), jnp.int32),
+                                      transpose_rhs=True, **kw)
+               for e in range(pos.shape[0])]
+    else:
+        per = [ternary_matmul(x, pos[e], neg[e], scales[e], **kw)
+               for e in range(pos.shape[0])]
+    per = np.stack([np.asarray(p) for p in per])
+    eid = np.asarray(eid)
+    return np.where((eid >= 0)[:, None], per[np.maximum(eid, 0),
+                                             np.arange(M)], 0.0)
+
+
+def planes_for(key, E, K, N, transpose):
+    """A plane stack in the kernel's form: [E, K, N//32], or [E, N, K//32]
+    for ``transpose`` (K a multiple of 32)."""
+    return (rand_plane_stack(key, E, N, K) if transpose
+            else rand_plane_stack(key, E, K, N))
+
+
+@pytest.mark.parametrize("M,K,N,E,layout,transpose", [
+    pytest.param(8, 32, 32, 1, "random", False, id="8-32-32-1"),
+    pytest.param(13, 96, 64, 3, "random", False, id="13-96-64-3"),
+    pytest.param(33, 64, 128, 4, "random", False, id="33-64-128-4"),
+    # row tiles of one expert, of -1 rows and mixed; M % bm != 0
+    (300, 64, 128, 3, "tiles", False),
+    (43, 64, 128, 3, "tiles", True),
+    (300, 64, 64, 1, "tiles", False),
+    (20, 64, 64, 1, "tiles", True),
+])
+def test_grouped_matmul_bit_identical_to_single(M, K, N, E, layout,
+                                                transpose):
     """Row-wise, the grouped kernel == the single-expert kernel run per
-    expert (same block shapes) with rows selected by expert id."""
-    pos, neg = rand_plane_stack(30, E, K, N)
-    x = jnp.asarray(np.random.default_rng(31).normal(0, 1, (M, K)),
-                    jnp.float32)
+    expert (same block shapes) with rows selected by expert id; where row
+    tiles skip the experts they lack, also bitwise the oracle."""
+    pos, neg = planes_for(30, E, K, N, transpose)
     scales = jnp.asarray(np.random.default_rng(32).normal(0, 0.5, E),
                          jnp.float32)
-    eid = jnp.asarray(np.random.default_rng(33).integers(0, E, M), jnp.int32)
+    if layout == "random":
+        x = jnp.asarray(np.random.default_rng(31).normal(0, 1, (M, K)),
+                        jnp.float32)
+        eid = jnp.asarray(np.random.default_rng(33).integers(0, E, M),
+                          jnp.int32)
+    else:
+        # row tiles: 8 rows in the rows form; a lane block (128) in the
+        # columns form, where rows are the lane dim of x^T
+        x = integer_x(M, K, 31)
+        eid = tiled_eid(M, E, 8 if transpose else 128, 33)
     kw = dict(bm=8, bk=32, bn=32, interpret=True)
-    got = ternary_matmul_grouped(x, pos, neg, scales, eid, **kw)
-    per = jnp.stack([ternary_matmul(x, pos[e], neg[e], scales[e], **kw)
-                     for e in range(E)])
-    want = per[eid, jnp.arange(M)]
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got = np.asarray(ternary_matmul_grouped(x, pos, neg, scales, eid,
+                                            transpose_rhs=transpose, **kw))
+    np.testing.assert_array_equal(
+        got, single_expert_rows(x, pos, neg, scales, eid, transpose, **kw))
+    if layout == "tiles":
+        np.testing.assert_array_equal(got, np.asarray(
+            ref.ternary_matmul_grouped_ref(x, pos, neg, scales, eid,
+                                           transpose_rhs=transpose)))
 
 
-def test_grouped_matmul_negative_rows_zero():
-    """expert_idx == -1 rows (base-only requests) get an exact zero delta."""
-    M, K, N, E = 9, 64, 64, 2
-    pos, neg = rand_plane_stack(34, E, K, N)
+@pytest.mark.parametrize("eid,transpose", [
+    pytest.param([0, -1, 1, -1, 0, 1, -1, 0, 1], False, id="mixed"),
+    # tiles of 8: all -1, one expert, mixed, and a partial all -1 tile
+    pytest.param([-1] * 8 + [1] * 8 + [0, -1, 1, -1, 0, 1, -1, 0] + [-1] * 3,
+                 False, id="tiles"),
+    pytest.param([-1] * 8 + [1] * 8 + [0, -1, 1, -1, 0, 1, -1, 0] + [-1] * 3,
+                 True, id="tiles-transpose"),
+])
+def test_grouped_matmul_negative_rows_zero(eid, transpose):
+    """expert_idx == -1 rows (base-only requests) get an exact zero delta;
+    a tile of -1 rows skips every expert, and the other rows are bitwise
+    their single-expert runs."""
+    M, K, N, E = len(eid), 64, 64, 2
+    pos, neg = planes_for(34, E, K, N, transpose)
     x = jnp.asarray(np.random.default_rng(35).normal(0, 1, (M, K)),
                     jnp.float32)
-    eid = jnp.asarray([0, -1, 1, -1, 0, 1, -1, 0, 1], jnp.int32)
-    got = ternary_matmul_grouped(x, pos, neg, jnp.ones((E,), jnp.float32),
-                                 eid, bm=8, bk=32, bn=32, interpret=True)
-    assert np.all(np.asarray(got)[np.asarray(eid) < 0] == 0.0)
+    eid = jnp.asarray(eid, jnp.int32)
+    kw = dict(bm=8, bk=32, bn=32, interpret=True)
+    scales = jnp.ones((E,), jnp.float32)
+    got = np.asarray(ternary_matmul_grouped(x, pos, neg, scales, eid,
+                                            transpose_rhs=transpose, **kw))
+    assert np.all(got[np.asarray(eid) < 0] == 0.0)
+    np.testing.assert_array_equal(
+        got, single_expert_rows(x, pos, neg, scales, eid, transpose, **kw))
+
+
+@pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "jnp"])
+def test_tile_presence_on_known_layouts(xp):
+    """The per-tile expert rule, in the kernel's jnp form and the host's
+    numpy mirror."""
+    one = xp.asarray(np.full(256, 1, np.int32))      # 2x128 rows, expert 1
+    np.testing.assert_array_equal(tile_presence(one, 128, 3, xp=xp),
+                                  [[0, 1, 0], [0, 1, 0]])
+    decode = xp.asarray(np.arange(32, dtype=np.int32) % 3)
+    np.testing.assert_array_equal(tile_presence(decode, 32, 3, xp=xp),
+                                  [[1, 1, 1]])
+    # a partial last tile pads with -1; -1 rows hold no expert
+    ragged = xp.asarray(np.asarray([2] * 8 + [-1] * 8 + [0, -1], np.int32))
+    np.testing.assert_array_equal(tile_presence(ragged, 8, 3, xp=xp),
+                                  [[0, 0, 1], [0, 0, 0], [1, 0, 0]])
 
 
 def test_grouped_matmul_transposed_matches_ref():
@@ -328,30 +432,44 @@ def test_ops_expert_dot_matches_core():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("M,K,N,transpose,bm,bn,bk", [
-    (5, 512, 6400, False, 128, 4096, 256),   # 200 words: 128 + edge of 72
-    (9, 256, 300, True, 8, 128, 512),        # 300 rows: 128, 128, edge 44
-    (260, 256, 96, False, 128, 4096, 128),   # rows pad to 384, 2 k-steps
+@pytest.mark.parametrize("M,K,N,transpose,bm,bn,bk,layout", [
+    # 200 words: 128 + edge of 72
+    pytest.param(5, 512, 6400, False, 128, 4096, 256, "random",
+                 id="5-512-6400-False-128-4096-256"),
+    # 300 rows: 128, 128, edge 44
+    pytest.param(9, 256, 300, True, 8, 128, 512, "random",
+                 id="9-256-300-True-8-128-512"),
+    # rows pad to 384, 2 k-steps
+    pytest.param(260, 256, 96, False, 128, 4096, 128, "random",
+                 id="260-256-96-False-128-4096-128"),
+    # 128-row tiles of one expert, of -1 rows, and a mixed partial tile
+    (300, 256, 96, False, 128, 4096, 128, "tiles"),
+    (300, 256, 300, True, 128, 128, 128, "tiles"),
 ])
 def test_grouped_matmul_edge_blocks_match_ref(M, K, N, transpose, bm, bn,
-                                              bk):
+                                              bk, layout):
     E = 3
     rng = np.random.default_rng(60)
-    if transpose:
-        pos, neg = rand_plane_stack(61, E, N, K)
+    pos, neg = planes_for(61, E, K, N, transpose)
+    if layout == "random":
+        x = jnp.asarray(rng.normal(0, 1, (M, K)), jnp.float32)
+        scales = jnp.asarray(rng.normal(0, 0.5, E), jnp.float32)
+        eid = jnp.asarray(rng.integers(-1, E, M), jnp.int32)
     else:
-        pos, neg = rand_plane_stack(61, E, K, N)
-    x = jnp.asarray(rng.normal(0, 1, (M, K)), jnp.float32)
-    scales = jnp.asarray(rng.normal(0, 0.5, E), jnp.float32)
-    eid = jnp.asarray(rng.integers(-1, E, M), jnp.int32)
-    got = ternary_matmul_grouped(x, pos, neg, scales, eid,
-                                 transpose_rhs=transpose, bm=bm, bn=bn,
-                                 bk=bk, interpret=True)
-    want = ref.ternary_matmul_grouped_ref(x, pos, neg, scales, eid,
-                                          transpose_rhs=transpose)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-4)
-    assert np.all(np.asarray(got)[np.asarray(eid) < 0] == 0.0)
+        x, eid = integer_x(M, K, 62), tiled_eid(M, E, bm, 63)
+        scales = jnp.asarray(rng.normal(0, 0.5, E), jnp.float32)
+    kw = dict(bm=bm, bn=bn, bk=bk, interpret=True)
+    got = np.asarray(ternary_matmul_grouped(x, pos, neg, scales, eid,
+                                            transpose_rhs=transpose, **kw))
+    want = np.asarray(ref.ternary_matmul_grouped_ref(
+        x, pos, neg, scales, eid, transpose_rhs=transpose))
+    if layout == "random":
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+    else:
+        np.testing.assert_array_equal(got, want)
+    assert np.all(got[np.asarray(eid) < 0] == 0.0)
+    np.testing.assert_array_equal(
+        got, single_expert_rows(x, pos, neg, scales, eid, transpose, **kw))
 
 
 @pytest.mark.parametrize("M,N,bm,bn", [(300, 6400, 128, 4096),

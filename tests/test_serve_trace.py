@@ -56,7 +56,7 @@ def smoke():
     api = build(cfg)
     base = api.init(jax.random.PRNGKey(0))
     experts = []
-    for i in range(2):
+    for i in range(3):
         leaves, tdef = jax.tree_util.tree_flatten(base)
         keys = jax.random.split(jax.random.PRNGKey(100 + i), len(leaves))
         ft = jax.tree_util.tree_unflatten(tdef, [
@@ -245,3 +245,33 @@ def test_benchmark_host_spans_attach_to_the_real_engine(smoke):
     assert "jit_" + eng._chunk_fn.__name__ == decode_step_ms.CHUNK_PROGRAM
     assert "jit_" + eng._prefill.__name__ == \
         prefill_ms_per_ktok.PREFILL_PROGRAM
+
+
+@pytest.mark.parametrize("n_experts,skipped", [(3, 2 / 3), (1, 0.0)],
+                         ids=["three_experts", "one_expert"])
+def test_prefill_expert_passes_follow_the_kernels_tile_rule(
+        smoke, tmp_path, n_experts, skipped):
+    """One FIFO wave of ``n_experts`` stacked experts (then one-row
+    refills) on 128-position KV blocks: every prefill row tile lies in one
+    request, so the grouped kernel skips all but one stacked expert per
+    tile; each program ``engine.prefill`` span names the stack's width."""
+    cfg = smoke[0]
+    eng = _engine(smoke, max_stack=3, cache_len=256, kv_block_size=128,
+                  scheduler="fifo")
+    reqs = _reqs(cfg, n=6)
+    for r in reqs:
+        r.expert = f"expert{r.uid % n_experts}"
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    c = eng.swap_summary()["counters"]
+    # the first prefill holds 3 rows of 128 positions: 3 tiles
+    assert c["prefill_expert_passes"] == n_experts * (
+        3 + c["prefill_calls"] - 1)
+    assert c["prefill_expert_passes_skipped"] / c["prefill_expert_passes"] \
+        == pytest.approx(skipped)
+    prog = [st for st in _spans(tmp_path)["engine.prefill"] if st]
+    assert len(prog) == c["prefill_calls"] > 1
+    assert all(st["stack_experts"] == n_experts for st in prog)

@@ -60,11 +60,19 @@ def _plane_copies(hlo: str, plane_shape) -> int:
     ("ffn_down", FF, D, False),
     ("tied_lm_head", D, VOCAB, True),
 ])
-@pytest.mark.parametrize("rows", [DECODE_ROWS, 512])
-def test_grouped_matmul_compiles(one_chip, name, k, n, transpose, rows):
+@pytest.mark.parametrize("rows,one_expert", [
+    pytest.param(DECODE_ROWS, False, id=str(DECODE_ROWS)),
+    pytest.param(512, False, id="512"),
+    # a prefill: four 128-row tiles of expert 1, the other two skipped
+    pytest.param(512, True, id="512-one_expert"),
+])
+def test_grouped_matmul_compiles(one_chip, name, k, n, transpose, rows,
+                                 one_expert):
     plane = (E, n, k // 32) if transpose else (E, k, n // 32)
 
     def f(x, pos, neg, scales, eid):
+        if one_expert:
+            eid = jnp.ones_like(eid)
         return ternary_matmul_grouped(x, pos, neg, scales, eid,
                                       transpose_rhs=transpose,
                                       interpret=False)
