@@ -337,12 +337,14 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
            "params": model.param_count(cfg), "trace": None, "host": None}
     result = {"correct": bool(correct), "attempted": summary["attempted"],
               "failed": summary["failed"]}
+    not_read: list = []
     if trace:
         from bench import trace_reduce
         rec["peaks"] = trace_reduce.peaks(dev["kind"])
         rec["trace"] = trace_reduce.reduce_dir(TRACE_DIR)
         rec["host"] = trace_reduce.host_counts(spans.records)
-        result["metrics"] = read_per_layer(files["per_layer"], rec)
+        result["metrics"], not_read = read_per_layer(files["per_layer"],
+                                                     rec)
         dev["busy_s"] = rec["trace"]["busy_s"]
         dev["window_s"] = rec["trace"]["window_s"]
         result["device"] = dev
@@ -359,6 +361,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                       "window_compiles": compiles["n"],
                       "finished": summary["finished"],
                       "tokens": summary["tokens"], "window_s": window_s}
+    if not_read:
+        result["info"]["metrics_not_read"] = not_read
     if control:
         result["info"]["program_gap_max"] = chk["program_gap_max"]
     result["check"] = compared
@@ -385,14 +389,28 @@ def end_to_end(metrics: list, summary: dict, setup_s: float) -> dict:
     return out
 
 
-def read_per_layer(metrics: list, rec: dict) -> dict:
+def _has(rec: dict, path) -> bool:
+    """Whether the run record holds a value at ``path`` (a key sequence)."""
+    for key in path:
+        if not isinstance(rec, dict) or rec.get(key) is None:
+            return False
+        rec = rec[key]
+    return True
+
+
+def read_per_layer(metrics: list, rec: dict) -> tuple[dict, list]:
     """Each per-layer metric listed for the cell, from its reader
-    ``metrics/<name>.py``.  A reader that finds nothing to read returns
-    None; for a metric the cell lists, that is an error, not a metric
-    left out of the line."""
+    ``metrics/<name>.py``, and the names of those left out.
+
+    A reader may declare what it reads as ``NEEDS``, a tuple of key paths
+    into the run record (``(("engine", "counters", "admit_host_s"),)``).
+    Where one of them is absent, as in a run of a program without that
+    counter, the metric is left out of the line and named in the list.
+    Otherwise a reader that finds nothing to read returns None, and for a
+    metric the cell lists that is an error, not a metric left out."""
     import importlib.util
 
-    out = {}
+    out, not_read = {}, []
     for m in metrics:
         path = HERE / "metrics" / f"{m['name']}.py"
         spec = importlib.util.spec_from_file_location(
@@ -400,12 +418,15 @@ def read_per_layer(metrics: list, rec: dict) -> dict:
             path)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
+        if not all(_has(rec, p) for p in getattr(mod, "NEEDS", ())):
+            not_read.append(m["name"])
+            continue
         v = mod.read(rec)
         if v is None:
             raise RuntimeError(f"per-layer metric {m['name']} found "
                                "nothing to read in this traced run")
         out[m["name"]] = {"value": v, "unit": m["unit"]}
-    return out
+    return out, not_read
 
 
 def print_result(result: dict) -> None:

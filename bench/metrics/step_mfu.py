@@ -1,7 +1,8 @@
 """The whole serving step's share of the chip's bf16 peak: 2 x parameters
 x tokens the model processed in the traced window (prompt tokens
 prefilled and output tokens decoded) over the traced window times the
-peak (whole step, device)."""
+peak (whole step, device).  The parameters are those one token's forward
+pass multiplies by, as the configuration's builder counts them."""
 
 
 def read(rec):

@@ -1,7 +1,9 @@
 """A configuration file of ``configs/`` as the program's model config.
 
 The file holds the published config's keys (Hugging Face names) as run,
-and the name of the plain reference module beside it.
+the name of its architecture's builder module (``"architecture"``) and
+that of the plain reference module (``"reference"``), both beside it.
+The builder's contract is in ``configs/qwen_dense.py``.
 """
 
 from __future__ import annotations
@@ -12,7 +14,6 @@ from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 CONFIG_DIR = HERE / "configs"
-MODEL_TYPES = ("qwen2", "qwen3")
 
 
 def load(name: str, config_dir: Path = CONFIG_DIR) -> dict:
@@ -20,33 +21,17 @@ def load(name: str, config_dir: Path = CONFIG_DIR) -> dict:
         return json.load(f)
 
 
-def head_dim(c: dict) -> int:
-    return int(c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"])
+def architecture(c: dict):
+    """The builder module named by the configuration."""
+    if "architecture" not in c:
+        raise ValueError(f"configuration {c.get('name')!r} names no builder "
+                         "module under 'architecture'")
+    return importlib.import_module(f"bench.configs.{c['architecture']}")
 
 
 def to_model_config(c: dict):
     """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import AttnCfg, BlockCfg, FFNCfg, ModelConfig
-
-    if c["model_type"] not in MODEL_TYPES:
-        raise ValueError(f"model_type {c['model_type']!r} not in "
-                         f"{MODEL_TYPES}")
-    if c["hidden_act"] != "silu":
-        raise ValueError(f"hidden_act {c['hidden_act']!r}: only silu")
-    block = BlockCfg(
-        kind="attn",
-        attn=AttnCfg(n_q=c["num_attention_heads"],
-                     n_kv=c["num_key_value_heads"], head_dim=head_dim(c),
-                     qkv_bias=c["model_type"] == "qwen2",
-                     qk_norm=c["model_type"] == "qwen3",
-                     rope_theta=float(c["rope_theta"])),
-        ffn=FFNCfg(d_ff=c["intermediate_size"], activation="swiglu"))
-    return ModelConfig(
-        name=c["name"], family="dense", d_model=c["hidden_size"],
-        vocab=c["vocab_size"], pattern=(block,),
-        n_units=c["num_hidden_layers"],
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        rms_eps=float(c["rms_norm_eps"]), dtype=c["torch_dtype"])
+    return architecture(c).to_model_config(c)
 
 
 def reference(c: dict):
@@ -55,12 +40,5 @@ def reference(c: dict):
 
 
 def param_count(c: dict) -> int:
-    """Parameters of the configuration as run (embedding, head, layers)."""
-    d, f = c["hidden_size"], c["intermediate_size"]
-    hq, hkv, hd = (c["num_attention_heads"], c["num_key_value_heads"],
-                   head_dim(c))
-    layer = (2 * d + d * hd * (2 * hq + 2 * hkv) + 3 * d * f
-             + ((hq + 2 * hkv) * hd if c["model_type"] == "qwen2" else 0)
-             + (2 * hd if c["model_type"] == "qwen3" else 0))
-    head = 0 if c["tie_word_embeddings"] else d * c["vocab_size"]
-    return (c["num_hidden_layers"] * layer + c["vocab_size"] * d + head + d)
+    """Parameters one token's forward pass multiplies by, as run."""
+    return architecture(c).param_count(c)

@@ -1,7 +1,9 @@
 """From a profiler trace (``.xplane.pb``) to the numbers the per-layer
 metrics read: device busy and idle time, device time per program and per
-operation, the grouped kernel's calls with their shapes, and the
-breakdown the result line carries.
+operation, the grouped kernel's calls with their shapes, every custom
+call's (kernel's) calls with their shapes, the benchmark's and the
+program's host spans with their attributes, and the breakdown the result
+line carries.
 
 Read through ``jax.profiler.ProfileData``; nothing else is needed.  A TPU
 trace has one plane per chip (``/device:TPU:<n>``) whose ``XLA Modules``
@@ -69,6 +71,10 @@ def _op_kind(name: str) -> str:
 
 
 _SHAPE = re.compile(r"(f32|bf16|u32|s32)\[([\d,]*)\]")
+# any element type (s8, f8e4m3fn, pred, ...) for the kernels of ``kernels``
+_TYPED_SHAPE = re.compile(r"\b([a-z][a-z0-9]*)\[([\d,]*)\]")
+CUSTOM_CALL = " custom-call("
+CALL_TARGET = ", custom_call_target="
 
 
 def grouped_shapes(text: str):
@@ -96,7 +102,20 @@ def grouped_shapes(text: str):
     return None
 
 
-def self_times(events) -> list:
+def call_shapes(text: str):
+    """``(result, operands)`` of a custom call from its HLO text, each a
+    list of ``(dtype, dims)``; None where the text is no custom call."""
+    if CUSTOM_CALL not in text:
+        return None
+    res, ops = text.split(CUSTOM_CALL, 1)
+
+    def typed(t):
+        return [(dt, tuple(int(d) for d in dims.split(",") if d))
+                for dt, dims in _TYPED_SHAPE.findall(t)]
+    return typed(res.split(" = ", 1)[-1]), typed(ops.split(CALL_TARGET)[0])
+
+
+def self_times(events, kind=lambda ev: _op_kind(ev.name)) -> list:
     """(kind, seconds) of each event less the events nested in it on the
     same line (a ``while`` holds its body's operations)."""
     evs = sorted(events, key=lambda e: (e.start_ns, -e.end_ns))
@@ -106,21 +125,44 @@ def self_times(events) -> list:
             stack.pop()
         if stack and ev.end_ns <= stack[-1][0]:
             out[stack[-1][1]][1] -= ev.duration_ns / 1e9
-        out.append([_op_kind(ev.name), ev.duration_ns / 1e9])
+        out.append([kind(ev), ev.duration_ns / 1e9])
         stack.append([ev.end_ns, len(out) - 1])
     return out
 
 
-def _host_spans(pd) -> list:
-    spans = []
-    for plane in pd.planes:
-        if plane.name.startswith("/device:"):
-            continue
-        for line in plane.lines:
-            for ev in line.events:
-                if ev.name.startswith(SPAN_PREFIXES):
-                    spans.append((ev.start_ns, ev.end_ns, ev.name))
-    return spans
+def _host_span_lines(pd) -> list:
+    """The benchmark's and the program's span events of each host line
+    (one thread each)."""
+    return [[ev for ev in line.events if ev.name.startswith(SPAN_PREFIXES)]
+            for plane in pd.planes if not plane.name.startswith("/device:")
+            for line in plane.lines]
+
+
+def _host_spans(lines) -> list:
+    """(start, end, name) of every span of :func:`_host_span_lines`."""
+    return [(ev.start_ns, ev.end_ns, ev.name) for line in lines
+            for ev in line]
+
+
+def span_totals(lines, w0, w1) -> dict:
+    """name -> count, seconds, self seconds (less the spans nested in it
+    on its line) and the sum of each numeric attribute, over the span
+    events of each line (:func:`_host_span_lines`) that lie inside
+    [w0, w1]."""
+    out: dict = {}
+    for line in lines:
+        evs = [ev for ev in line if w0 <= ev.start_ns and ev.end_ns <= w1]
+        for ev in evs:
+            t = out.setdefault(ev.name, {"count": 0, "seconds": 0.0,
+                                         "self_seconds": 0.0, "attrs": {}})
+            t["count"] += 1
+            t["seconds"] += ev.duration_ns / 1e9
+            for k, v in ev.stats:
+                if isinstance(v, (int, float)):
+                    t["attrs"][k] = t["attrs"].get(k, 0) + v
+        for name, sec in self_times(evs, kind=lambda ev: ev.name):
+            out[name]["self_seconds"] += sec
+    return out
 
 
 def _span_at(spans, t: float) -> str:
@@ -154,13 +196,15 @@ def reduce(pd) -> dict:
     planes = [p for p in pd.planes if p.name.startswith(DEVICE_PREFIX)]
     if not planes:
         raise ValueError("the trace holds no TPU device plane")
-    spans = _host_spans(pd)
+    span_lines = _host_span_lines(pd)
+    spans = _host_spans(span_lines)
     w0, w1 = next(((s, e) for s, e, name in spans if name == WINDOW_SPAN),
                   None) or profile_window(pd)
     busy_total = 0.0
     programs: dict = collections.defaultdict(float)
     ops: dict = collections.defaultdict(float)
     kernels: list = []
+    calls: dict = collections.defaultdict(list)
     gaps: dict = collections.defaultdict(float)
     for plane in planes:
         lines = {ln.name: ln for ln in plane.lines}
@@ -176,9 +220,15 @@ def reduce(pd) -> dict:
         for kind, sec in self_times(op_ev):
             ops[kind] += sec
         for ev in op_ev:
-            if _op_kind(ev.name) == GROUPED_KERNEL:
+            kind = _op_kind(ev.name)
+            if kind == GROUPED_KERNEL:
                 kernels.append({"seconds": ev.duration_ns / 1e9,
                                 "shape": grouped_shapes(ev.name)})
+            shapes = call_shapes(ev.name)
+            if shapes is not None:
+                calls[kind].append({"seconds": ev.duration_ns / 1e9,
+                                    "result": shapes[0],
+                                    "operands": shapes[1]})
         if MODULES_LINE in lines:
             for ev in lines[MODULES_LINE].events:
                 name = _module_name(ev.name)
@@ -191,6 +241,8 @@ def reduce(pd) -> dict:
         "busy_s": busy_total / 1e9 / n,
         "programs": {k: v / n for k, v in programs.items()},
         "grouped_kernel": kernels,
+        "kernels": dict(calls),
+        "spans": span_totals(span_lines, w0, w1),
         "breakdown": {"device_ops": [[k, v / n] for k, v in top_ops],
                       "idle_gaps": [[k, v / n] for k, v in top_gaps]},
     }

@@ -26,6 +26,8 @@ NORMS = ("pre_norm", "ffn_norm", "final_norm", "q_norm", "k_norm")
 BIASES = ("bq", "bk", "bv")
 EXPERT_SCALE_OF_STD = 0.25   # an expert's |delta| as a share of the leaf std
 NORM_EXPERT_SCALE = 0.05
+# a block leaf stacked over routed experts (the program's wg_e, wu_e, wo_e)
+EXPERT_AXIS_SUFFIX = "_e"
 
 
 def root_key(seed: int) -> jax.Array:
@@ -60,8 +62,11 @@ class Leaf:
             return 0.0
         if self.name == "embed" or self.name in BIASES:
             return 0.02
-        fan_in = (int(np.prod(self.core[:-1])) if self.name == "wo"
-                  else self.core[0])
+        name, core = self.name, self.core
+        if self.units and name.endswith(EXPERT_AXIS_SUFFIX):
+            # [E, ...]: one routed expert's matrix per entry of the axis
+            name, core = name[:-len(EXPERT_AXIS_SUFFIX)], core[1:]
+        fan_in = int(np.prod(core[:-1])) if name == "wo" else core[0]
         return float(1.0 / np.sqrt(fan_in))
 
     @property
